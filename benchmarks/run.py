@@ -40,6 +40,8 @@ def main() -> None:
                              "persistence_bench"])
     args = ap.parse_args()
 
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows = []
 
     from benchmarks.common import total_compiles

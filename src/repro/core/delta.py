@@ -225,11 +225,11 @@ def _scan_delta(delta: DeltaStore, queries: jax.Array, *, k: int,
     k_scan = min(cap, k + margin)
     qvals, qrows = scan_topk_quantized(
         queries, delta.qdata, delta.qvmin, delta.qscale, valid, k=k_scan,
-        chunk=1, block_n=128)
+        chunk=1)
     rows = jnp.clip(qrows, 0, cap - 1)
     vecs = delta.vectors[rows]                                # (Q, k_scan, d)
-    exact = jnp.einsum("qd,qrd->qr", queries.astype(jnp.float32),
-                       vecs)
+    exact = jnp.einsum("qd,qrd->qr", queries.astype(jnp.float32), vecs,
+                       precision=jax.lax.Precision.HIGHEST)
     exact = jnp.where(jnp.logical_and(qrows >= 0, jnp.isfinite(qvals)),
                       exact, -jnp.inf)
     kk = min(k, exact.shape[1])

@@ -237,9 +237,10 @@ class HMGIIndex:
             et = edges[2] if len(edges) > 2 else None
             ew = edges[3] if len(edges) > 3 else None
             self.graph = graph_from_edges(n_nodes, src, dst, et, ew)
-            self.communities = comm_mod.louvain_one_level(
-                n_nodes, np.asarray(src), np.asarray(dst),
-                np.ones(len(src)) if ew is None else np.asarray(ew))
+            with obs.span("index.communities"):
+                self.communities = comm_mod.louvain_one_level(
+                    n_nodes, np.asarray(src), np.asarray(dst),
+                    np.ones(len(src)) if ew is None else np.asarray(ew))
             self.boosted_weights = comm_mod.community_edge_boost(
                 self.graph, self.communities)
         if node_attrs is not None:

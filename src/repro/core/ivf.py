@@ -46,45 +46,30 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                       # newer jax spells it jax.shard_map
-    _shard_map = jax.shard_map
-except AttributeError:                     # 0.4.x: jax.experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-# the replication-check kwarg was renamed check_rep -> check_vma on a
-# different version boundary than the alias promotion: probe the signature
-import inspect as _inspect
-_SHARD_MAP_KW = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else {"check_rep": False})
-
 from repro.common.shapes import pad_to_chunk
 from repro.core import partitioner
 from repro.core.graph_store import mask_pass
 from repro.core.quantization import QuantizedVectors, quantize
+from repro.kernels.ivf_topk.ivf_topk import block_rows
 from repro.kernels.ivf_topk.ops import (_interpret_mode,
                                         scan_topk_quantized_batched)
 from repro.kernels.ivf_topk.ref import pad_topk
 
-# probe-path kernel tiling: chunk-of-16 survivors, 512-row blocks (see
-# kernels/ivf_topk/ivf_topk.py for the VMEM accounting)
+# probe-path kernel tiling: chunk-of-16 survivors (see
+# kernels/ivf_topk/ivf_topk.py for the layout and VMEM accounting)
 _CHUNK = 16
-_BLOCK_N = 512
 
 
-def _probe_block_n(m: int, qb: int, d: int) -> int:
-    """Row-block size for the probe scan. On TPU the tile keeps the per-step
-    data block — int8 plus its in-register fp32 cast, 5 bytes/element over
-    (qb, bn, d) — near 8 MB of VMEM, so the (qb, P, cap, d) fp32 intermediate
-    the einsum path writes to HBM never exists. Under the interpreter each
-    grid step costs fixed overhead and padding to a block multiple is pure
-    waste (P·cap is rarely block-aligned), so the whole per-query slab runs
-    as one step, padded only to the chunk size."""
+def _probe_block_n(m: int) -> int:
+    """Row-block size for the probe scan of an m-row per-query slab. On TPU
+    it is the kernel's default (128 survivor lanes × chunk = 2048 rows: a
+    lane-dense output tile, and ~2·2048·d int8 bytes of double-buffered
+    VMEM). Under the interpreter each grid step costs fixed overhead and
+    padding to a block multiple is pure waste, so the whole per-query slab
+    runs as one step, padded only to the chunk size."""
     if _interpret_mode():
         return pad_to_chunk(m, _CHUNK)
-    budget = 8 * 1024 * 1024
-    bn = budget // (5 * max(qb, 1) * max(d, 1))
-    return max(_CHUNK, min(_BLOCK_N, (bn // _CHUNK) * _CHUNK))
+    return block_rows(m, _CHUNK)
 
 
 @functools.partial(
@@ -179,20 +164,26 @@ def build(key, vectors: jax.Array, ids: jax.Array, *, n_partitions: int,
 # flat slab indices (partition p's slots are [p·cap, (p+1)·cap), matching
 # ``slab_view``). Host-side orchestration — dynamic shapes are fine here.
 
+def _part_slot(index: IVFIndex, rows):
+    """Flat slab rows -> (partition, slot) index pairs. The stores are
+    indexed as (K, cap, …): on TPU, flattening (K, cap) with cap not a
+    multiple of the memory tile is a relayout copy of the whole slab, and
+    XLA fuses it into every gather or scatter that reads the flat view."""
+    rows = jnp.asarray(rows, jnp.int32)
+    return rows // index.capacity, rows % index.capacity
+
+
 def set_slots(index: IVFIndex, rows, data, vmin, scale, ids) -> IVFIndex:
     """Writes quantized rows (byte-identical) into the given flat slab slots
     and refreshes the per-partition counts."""
-    k, cap = index.ids.shape
-    rows = jnp.asarray(rows, jnp.int32)
-    flat_ids = index.ids.reshape(-1).at[rows].set(jnp.asarray(ids, jnp.int32))
+    p, c = _part_slot(index, rows)
+    new_ids = index.ids.at[p, c].set(jnp.asarray(ids, jnp.int32))
     return index._replace(
-        data=index.data.reshape(k * cap, -1).at[rows].set(data)
-            .reshape(index.data.shape),
-        vmin=index.vmin.reshape(-1).at[rows].set(vmin).reshape(k, cap),
-        scale=index.scale.reshape(-1).at[rows].set(scale).reshape(k, cap),
-        ids=flat_ids.reshape(k, cap),
-        counts=jnp.sum(flat_ids.reshape(k, cap) >= 0, axis=1,
-                       dtype=jnp.int32))
+        data=index.data.at[p, c].set(data),
+        vmin=index.vmin.at[p, c].set(vmin),
+        scale=index.scale.at[p, c].set(scale),
+        ids=new_ids,
+        counts=jnp.sum(new_ids >= 0, axis=1, dtype=jnp.int32))
 
 
 def clear_slots(index: IVFIndex, rows) -> IVFIndex:
@@ -209,9 +200,8 @@ def clear_slots(index: IVFIndex, rows) -> IVFIndex:
 def gather_slots(index: IVFIndex, rows):
     """(data, vmin, scale, ids) of the given flat slab slots — the stored
     bytes, ready to be ``set_slots`` elsewhere byte-identically."""
-    data, vmin, scale, ids = index.slab_view()
-    rows = jnp.asarray(rows, jnp.int32)
-    return data[rows], vmin[rows], scale[rows], ids[rows]
+    p, c = _part_slot(index, rows)
+    return index.data[p, c], index.vmin[p, c], index.scale[p, c], index.ids[p, c]
 
 
 def _dequant_rows(index: IVFIndex, rows_data, rows_vmin, rows_scale):
@@ -271,7 +261,13 @@ def search(index: IVFIndex, queries: jax.Array, *, n_probe: int, k: int,
     qp = jnp.pad(q, ((0, pad), (0, 0)))
     pp = jnp.pad(probe, ((0, pad), (0, 0)))
     nblocks = qp.shape[0] // qb
-    slab_data, slab_vmin, slab_scale, slab_ids = index.slab_view()
+    # per-query probe slab: n_probe whole partitions as (partition, slot)
+    # pairs (see ``_part_slot``), padded with slot -1 to a whole number of
+    # kernel blocks (padding the gathered int8 slab afterwards would copy
+    # it a second time)
+    m = probe.shape[1] * cap
+    block_n = _probe_block_n(m)
+    m_pad = pad_to_chunk(m, block_n)
 
     def _row_valid(bids):
         """Slot occupancy ∧ predicate pushdown (pre-top-k filtering)."""
@@ -282,21 +278,21 @@ def search(index: IVFIndex, queries: jax.Array, *, n_probe: int, k: int,
     def block_kernel(carry, i):
         qs = jax.lax.dynamic_slice_in_dim(qp, i * qb, qb, axis=0)      # (qb, d)
         ps = jax.lax.dynamic_slice_in_dim(pp, i * qb, qb, axis=0)      # (qb, P)
-        # probed partitions = contiguous row blocks of the flat slab
-        rows = (ps[:, :, None] * cap
-                + jnp.arange(cap, dtype=jnp.int32)[None, None, :])
-        rows = rows.reshape(qb, -1)                                     # (qb, M)
-        bdata = slab_data[rows]                                         # int8!
-        bmin = slab_vmin[rows]
-        bscale = slab_scale[rows]
-        bids = slab_ids[rows]                                           # (qb, M)
+        part = jnp.pad(jnp.repeat(ps, cap, axis=1), ((0, 0), (0, m_pad - m)))
+        slot = jnp.pad(jnp.tile(jnp.arange(cap, dtype=jnp.int32),
+                                (qb, probe.shape[1])),
+                       ((0, 0), (0, m_pad - m)), constant_values=-1)    # (qb, M)
+        s0 = jnp.maximum(slot, 0)
+        bdata = index.data[part, s0]                                    # int8!
+        bmin = index.vmin[part, s0]
+        bscale = index.scale[part, s0]
+        bids = jnp.where(slot >= 0, index.ids[part, s0], -1)            # (qb, M)
         vals, pos = scan_topk_quantized_batched(
             qs, bdata, bmin, bscale, _row_valid(bids), k=k,
-            chunk=_CHUNK, block_n=_probe_block_n(rows.shape[1], qb,
-                                                 qs.shape[1]))
+            chunk=_CHUNK, block_n=block_n)
         ids = jnp.where(pos >= 0,
                         jnp.take_along_axis(
-                            bids, jnp.clip(pos, 0, rows.shape[1] - 1), axis=1),
+                            bids, jnp.clip(pos, 0, m_pad - 1), axis=1),
                         -1)
         return carry, (vals, ids)
 
@@ -308,7 +304,8 @@ def search(index: IVFIndex, queries: jax.Array, *, n_probe: int, k: int,
         bscale = index.scale[ps]
         bids = index.ids[ps]                                            # (qb,P,cap)
         vecs = _dequant_rows(index, bdata, bmin, bscale)                # (qb,P,cap,d)
-        scores = jnp.einsum("qd,qpcd->qpc", qs, vecs)
+        scores = jnp.einsum("qd,qpcd->qpc", qs, vecs,
+                            precision=jax.lax.Precision.HIGHEST)
         scores = jnp.where(_row_valid(bids), scores, -jnp.inf)
         flat = scores.reshape(qb, -1)
         fids = bids.reshape(qb, -1)
@@ -326,7 +323,9 @@ def search(index: IVFIndex, queries: jax.Array, *, n_probe: int, k: int,
 def brute_force(vectors: jax.Array, valid: jax.Array, ids: jax.Array,
                 queries: jax.Array, *, k: int):
     """Monolithic-baseline / delta-store scoring: exact matmul + top-k."""
-    scores = queries.astype(jnp.float32) @ vectors.astype(jnp.float32).T
+    scores = jnp.matmul(queries.astype(jnp.float32),
+                        vectors.astype(jnp.float32).T,
+                        precision=jax.lax.Precision.HIGHEST)
     scores = jnp.where(valid[None, :], scores, -jnp.inf)
     vals, pos = jax.lax.top_k(scores, min(k, vectors.shape[0]))
     return vals, ids[pos]
@@ -464,10 +463,10 @@ def search_sharded(index: IVFIndex, queries: jax.Array, mesh, *, n_probe: int,
     if have_pass:
         in_specs.append(P(None))
         args.append(node_pass)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(None, None), P(None, None)),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     return fn(*args)

@@ -31,17 +31,23 @@ class KMeansState(NamedTuple):
     inertia: jax.Array          # scalar: mean squared distance
 
 
+def _dot_t(x: jax.Array, c: jax.Array) -> jax.Array:
+    """x @ c.T in full fp32 (XLA:TPU would run the default-precision matmul
+    at bf16, and probe selection must not depend on the backend)."""
+    return jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)
+
+
 def assign(x: jax.Array, centroids: jax.Array) -> jax.Array:
     """Eq. 1: nearest-centroid ids for x (N, d). One matmul + argmax."""
     half_sq = 0.5 * jnp.sum(centroids * centroids, axis=-1)       # (K,)
-    scores = x @ centroids.T - half_sq[None, :]                   # (N, K)
+    scores = _dot_t(x, centroids) - half_sq[None, :]              # (N, K)
     return jnp.argmax(scores, axis=-1).astype(jnp.int32)
 
 
 def assign_topk(x: jax.Array, centroids: jax.Array, k: int):
     """Top-k nearest centroids (used for n_probe partition selection)."""
     half_sq = 0.5 * jnp.sum(centroids * centroids, axis=-1)
-    scores = x @ centroids.T - half_sq[None, :]
+    scores = _dot_t(x, centroids) - half_sq[None, :]
     vals, idx = jax.lax.top_k(scores, k)
     return idx.astype(jnp.int32), vals
 

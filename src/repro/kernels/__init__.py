@@ -13,11 +13,10 @@ app-level JAX setup (``jax.distributed.initialize``) still runs first.
                      corpus slab shared by all queries (delta store,
                      monolithic baseline); ``scan_topk_quantized_batched``
                      scans per-query slabs — the IVF probe path gathers each
-                     query's probed partitions as contiguous row blocks of
-                     the flattened (K·cap, d) index slab (see
-                     ``core/ivf.py:IVFIndex.slab_view``) and rescores the
-                     top-k chunk survivors exactly. int8 rows never
-                     dequantize to fp32 in HBM on either path.
+                     query's probed partitions (whole (cap, d) blocks of the
+                     (K, cap, d) index slab) and rescores the top-k chunk
+                     survivors exactly. int8 rows never dequantize to fp32
+                     in HBM on either path.
   segment_reduce   — one-hot-matmul segment sum (GNN message passing,
                      EmbeddingBag reduce; MXU-friendly scatter replacement)
   decode_attention — GQA single-token flash-decode with online softmax

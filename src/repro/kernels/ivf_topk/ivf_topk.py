@@ -5,29 +5,39 @@ Computes, for a query block against a quantized corpus slab:
     score[q, n] = scale[n] · (Q[q] · D_int8[n]) + (128·scale[n] + vmin[n]) · Σ_d Q[q,d]
 
 (the affine-dequant identity — int8 rows never materialise as fp32 in HBM),
-then reduces each ``chunk`` of consecutive rows to its (max, argmax). The
-final exact top-k over (N/chunk) survivors happens outside in jnp — survivors
-are tiny. This is the TPU-native ANN layout (partial-reduce scan; cf.
-"TPU-KNN at Peak FLOP/s"): all FLOPs are one MXU matmul per (query-block ×
-row-block), HBM traffic is int8, and no sort runs inside the kernel.
+then reduces each *chunk* of rows to its (max, argmax). The final exact
+top-k over the N/chunk survivors happens outside in jnp — survivors are
+tiny. This is the TPU-native ANN layout (partial-reduce scan; cf.
+"TPU-KNN at Peak FLOP/s"): the FLOPs are MXU matmuls, HBM traffic is int8,
+and no sort runs inside the kernel.
 
-VMEM budget per grid step (defaults bq=256, bn=512, d≤1024, fp32 scores):
-  Q block 256·d·4 ≤ 1 MB, D block 512·d ≤ 0.5 MB (int8), scores 256·512·4
-  = 0.5 MB, outputs 2·256·(512/chunk)·4 — comfortably inside 16 MB VMEM,
-  MXU dims (256×d)·(d×512) aligned to the 128-lane systolic array.
+Chunk layout (strided). A row block of ``block_n`` rows holds
+``width = block_n // chunk`` chunks; chunk ``c`` of block ``b`` is the rows
+``b·block_n + j·width + c`` for ``j < chunk``. The kernel scores the block
+as ``chunk`` lane-aligned (rows, width) slabs and keeps an elementwise
+running (max, argmax) over them, so the survivor outputs are lane-dense
+(…, width) tiles and no in-kernel reshape or lane reduction is needed.
+``ref.py`` implements the same layout; ``chunk_rows`` maps survivor
+positions back to rows.
+
+TPU tiling (Mosaic): the last two dims of every block must be multiples of
+(8, 128) — (32, 128) for the int8 data — or equal the array's dims. So
+``width`` is a multiple of 128 whenever a scan spans more than one block;
+``block_rows`` picks such blocks. VMEM per grid step: the int8 data block
+(double-buffered, 2·block_n·d bytes), one fp32 (width, d) slice at a time,
+and (rows, width) fp32 scores — 2048 rows × d=384 is ~1.8 MB, inside the
+default scoped VMEM at every supported width.
 
 Two entry points share the kernel math:
 
   scan_topk_pallas         — one corpus slab shared by every query (the
-                             delta-store scan, monolithic baselines).
+                             delta-store scan, monolithic baselines). Grid
+                             (query blocks, row blocks).
   scan_topk_pallas_batched — per-query slabs (Q, M, d): the IVF probe path,
                              where each query gathered its own probed
                              partitions as contiguous row blocks of the
-                             flattened (K·cap, d) index slab. The grid runs
-                             over row blocks only; the query axis stays inside
-                             one batched dot_general per step, so interpret
-                             mode pays O(M/block_n) interpreter steps, not
-                             O(Q·M/block_n).
+                             flattened (K·cap, d) index slab. Grid
+                             (queries, row blocks), one query per step.
 """
 from __future__ import annotations
 
@@ -37,145 +47,138 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANES = 128                        # TPU vreg lane width
+_BLOCK_Q = 256                     # query rows per block of the flat kernel
+_HIGHEST = jax.lax.Precision.HIGHEST
 
-def _kernel(q_ref, qsum_ref, d_ref, aff_ref, scale_ref, bias_ref,
-            smax_ref, sarg_ref, *, chunk: int, block_n: int):
-    # q_ref:    (bq, d)      fp32   — query block (resident across grid)
-    # qsum_ref: (bq, 1)      fp32   — per-query Σ_d q
-    # d_ref:    (bn, d)      int8   — corpus rows for this grid step
-    # aff_ref:  (bn, 1)      fp32   — 128·scale + vmin   (affine term)
-    # scale_ref:(bn, 1)      fp32
-    # bias_ref: (bn, 1)      fp32   — 0 for live rows, -3e38 for masked rows
-    # smax_ref: (bq, bn/chunk) fp32 — per-chunk max scores (output block)
-    # sarg_ref: (bq, bn/chunk) int32 — per-chunk argmax (row within slab)
-    n = pl.program_id(0)
-    q = q_ref[...]
-    d = d_ref[...].astype(jnp.float32)
-    dots = jax.lax.dot_general(q, d, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)   # (bq, bn)
-    scores = (dots * scale_ref[...][:, 0][None, :]
-              + qsum_ref[...] * aff_ref[...][:, 0][None, :]
-              + bias_ref[...][:, 0][None, :])
-    bq = scores.shape[0]
-    nchunks = block_n // chunk
-    sc = scores.reshape(bq, nchunks, chunk)
-    smax_ref[...] = jnp.max(sc, axis=-1)
-    arg = jnp.argmax(sc, axis=-1).astype(jnp.int32)                  # (bq, nchunks)
-    base = n * block_n + jnp.arange(nchunks, dtype=jnp.int32) * chunk
-    sarg_ref[...] = arg + base[None, :]
+
+def block_rows(n: int, chunk: int, block_n: int | None = None) -> int:
+    """Row-block size for an n-row scan: ``block_n`` (default
+    ``LANES·chunk``, the smallest TPU-legal multi-block size), or a single
+    block of n rounded up to the chunk when the scan fits in one."""
+    block_n = LANES * chunk if block_n is None else int(block_n)
+    assert block_n % chunk == 0, (block_n, chunk)
+    return min(block_n, -(-n // chunk) * chunk)
+
+
+def chunk_rows(cpos, chunk: int, block_n: int):
+    """Rows of the strided chunks ``cpos`` (…, kc) -> (…, kc·chunk)."""
+    width = block_n // chunk
+    start = (cpos // width) * block_n + cpos % width
+    rows = start[..., None] + jnp.arange(chunk, dtype=jnp.int32) * width
+    return rows.reshape(*cpos.shape[:-1], -1)
+
+
+def _scan_block(q, qsum, d_ref, t_ref, chunk: int, width: int, base):
+    """(rows, width) running (max, argmax) over the block's ``chunk``
+    strided slabs. q (rows, d) fp32; qsum (rows, 1); t_ref (3, block_n)
+    fp32 = (affine, scale, bias) per row; base = the block's first row."""
+    best = arg = None
+    for j in range(chunk):
+        lo, hi = j * width, (j + 1) * width
+        d = d_ref[lo:hi, :].astype(jnp.float32)                        # (W, d)
+        dots = jax.lax.dot_general(q, d, (((1,), (1,)), ((), ())),
+                                   precision=_HIGHEST,
+                                   preferred_element_type=jnp.float32)
+        aff, scale, bias = (t_ref[i:i + 1, lo:hi] for i in range(3))
+        s = dots * scale + qsum * aff + bias                           # (r, W)
+        if best is None:
+            best, arg = s, jnp.zeros(s.shape, jnp.int32)
+        else:
+            upd = s > best                 # strict: ties keep the lower row
+            best = jnp.where(upd, s, best)
+            arg = jnp.where(upd, j, arg)
+    lane = jax.lax.broadcasted_iota(jnp.int32, best.shape, 1)
+    return best, base + arg * width + lane
+
+
+def _kernel(q_ref, qsum_ref, d_ref, t_ref, smax_ref, sarg_ref, *,
+            chunk: int, block_n: int):
+    # q_ref (bq, d) fp32 · qsum_ref (bq, 1) · d_ref (bn, d) int8 ·
+    # t_ref (3, bn) fp32 (affine, scale, bias) · outputs (bq, bn/chunk).
+    # The batched variant runs it with bq = 1 on that query's own rows.
+    base = pl.program_id(1) * block_n
+    smax, sarg = _scan_block(q_ref[...], qsum_ref[...], d_ref, t_ref,
+                             chunk, block_n // chunk, base)
+    smax_ref[...] = smax
+    sarg_ref[...] = sarg
+
+
+def _terms(vmin, scale, bias):
+    """Stacks the per-row dequant terms (affine, scale, bias) on axis -2."""
+    bias = jnp.zeros_like(scale) if bias is None else bias
+    return jnp.stack([128.0 * scale + vmin, scale,
+                      bias.astype(jnp.float32)], axis=-2)
 
 
 def scan_topk_pallas(queries, data_i8, vmin, scale, bias=None, *,
-                     chunk: int = 128, block_n: int = 512,
+                     chunk: int = 8, block_n: int = 1024,
                      interpret: bool = False):
     """queries (Q, d) fp32; data_i8 (N, d) int8 (centered at -128);
     vmin/scale (N,) fp32; bias (N,) fp32 or None (0 live, -3e38 masked).
-    Returns (chunk_max (Q, N/chunk), chunk_arg)."""
+    N must be a multiple of block_n. Queries run in blocks of 256 (fewer
+    run as one block), which bounds VMEM at the serving batch. Returns
+    (chunk_max (Q, N/chunk), chunk_arg) in the strided chunk layout
+    (module docstring)."""
     qn, d = queries.shape
     n = data_i8.shape[0]
     assert n % block_n == 0 and block_n % chunk == 0, (n, block_n, chunk)
-    nchunks_total = n // chunk
-    nblocks = n // block_n
-    per_block = block_n // chunk
-
-    qsum = jnp.sum(queries, axis=-1, keepdims=True)                  # (Q, 1)
-    aff = (128.0 * scale + vmin).reshape(n, 1)
-    scale2 = scale.reshape(n, 1)
-    bias2 = (jnp.zeros((n, 1), jnp.float32) if bias is None
-             else bias.reshape(n, 1).astype(jnp.float32))
-
-    out_shapes = (
-        jax.ShapeDtypeStruct((qn, nchunks_total), jnp.float32),
-        jax.ShapeDtypeStruct((qn, nchunks_total), jnp.int32),
-    )
-    grid = (nblocks,)
-    return pl.pallas_call(
+    width = block_n // chunk
+    bq = min(qn, _BLOCK_Q)
+    qpad = (-qn) % bq
+    q32 = jnp.pad(queries.astype(jnp.float32), ((0, qpad), (0, 0)))
+    qsum = jnp.sum(q32, axis=-1, keepdims=True)                       # (Q, 1)
+    out_shape = (qn + qpad, n // chunk)
+    cmax, carg = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk, block_n=block_n),
-        grid=grid,
+        grid=((qn + qpad) // bq, n // block_n),
         in_specs=[
-            pl.BlockSpec((qn, d), lambda i: (0, 0)),                  # queries
-            pl.BlockSpec((qn, 1), lambda i: (0, 0)),                  # qsum
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),             # data
-            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),             # affine
-            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),             # scale
-            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),             # bias
+            pl.BlockSpec((bq, d), lambda i, j: (i, 0)),               # queries
+            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),               # qsum
+            pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),          # data
+            pl.BlockSpec((3, block_n), lambda i, j: (0, j)),          # terms
         ],
         out_specs=(
-            pl.BlockSpec((qn, per_block), lambda i: (0, i)),
-            pl.BlockSpec((qn, per_block), lambda i: (0, i)),
+            pl.BlockSpec((bq, width), lambda i, j: (i, j)),
+            pl.BlockSpec((bq, width), lambda i, j: (i, j)),
         ),
-        out_shape=out_shapes,
+        out_shape=(jax.ShapeDtypeStruct(out_shape, jnp.float32),
+                   jax.ShapeDtypeStruct(out_shape, jnp.int32)),
         interpret=interpret,
-    )(queries.astype(jnp.float32), qsum, data_i8, aff, scale2, bias2)
-
-
-def _kernel_batched(q_ref, qsum_ref, d_ref, aff_ref, scale_ref, bias_ref,
-                    smax_ref, sarg_ref, *, chunk: int, block_n: int):
-    # q_ref:    (bq, d)          fp32 — query block (resident across grid)
-    # qsum_ref: (bq, 1)          fp32 — per-query Σ_d q
-    # d_ref:    (bq, bn, d)      int8 — each query's own slab rows
-    # aff/scale/bias_ref: (bq, bn) fp32 — per-(query, row) dequant terms
-    # smax/sarg_ref: (bq, bn/chunk) — per-chunk (max, argmax) output block
-    n = pl.program_id(0)
-    q = q_ref[...][:, None, :]                                        # (bq,1,d)
-    d = d_ref[...].astype(jnp.float32)                                # (bq,bn,d)
-    dots = jax.lax.dot_general(q, d, (((2,), (2,)), ((0,), (0,))),
-                               preferred_element_type=jnp.float32)[:, 0, :]
-    scores = (dots * scale_ref[...] + qsum_ref[...] * aff_ref[...]
-              + bias_ref[...])                                        # (bq, bn)
-    bq = scores.shape[0]
-    nchunks = block_n // chunk
-    sc = scores.reshape(bq, nchunks, chunk)
-    smax_ref[...] = jnp.max(sc, axis=-1)
-    base = n * block_n + jnp.arange(nchunks, dtype=jnp.int32) * chunk
-    sarg_ref[...] = jnp.argmax(sc, axis=-1).astype(jnp.int32) + base[None, :]
+    )(q32, qsum, data_i8, _terms(vmin, scale, bias))
+    return cmax[:qn], carg[:qn]
 
 
 def scan_topk_pallas_batched(queries, data_i8, vmin, scale, bias=None, *,
-                             chunk: int = 16, block_n: int = 512,
+                             chunk: int = 16, block_n: int = 2048,
                              interpret: bool = False):
     """Per-query-slab variant: queries (Q, d) fp32; data_i8 (Q, M, d) int8
-    (centered at -128); vmin/scale/bias (Q, M) fp32. Returns
-    (chunk_max (Q, M/chunk), chunk_arg) — chunk_arg indexes rows of each
-    query's own slab.
-
-    VMEM per grid step is Q·block_n·d·5 bytes for the data block — int8
-    storage plus the fp32 cast the matmul consumes (the whole query axis
-    rides along). The probe path sizes block_n from an ~8 MB budget (see
-    ``core/ivf.py:_probe_block_n``); callers picking block_n by hand should
-    keep Q·block_n·d·5 well under the 16 MB/core VMEM.
-    """
+    (centered at -128); vmin/scale/bias (Q, M) fp32; M a multiple of
+    block_n. Returns (chunk_max (Q, M/chunk), chunk_arg) — chunk_arg
+    indexes rows of each query's own slab, strided chunk layout."""
     qn, d = queries.shape
     m = data_i8.shape[1]
     assert m % block_n == 0 and block_n % chunk == 0, (m, block_n, chunk)
-    nblocks = m // block_n
-    nchunks_total = m // chunk
-    per_block = block_n // chunk
-
-    qsum = jnp.sum(queries.astype(jnp.float32), axis=-1, keepdims=True)
-    aff = 128.0 * scale + vmin                                        # (Q, M)
-    bias2 = (jnp.zeros((qn, m), jnp.float32) if bias is None
-             else bias.astype(jnp.float32))
-
-    out_shapes = (
-        jax.ShapeDtypeStruct((qn, nchunks_total), jnp.float32),
-        jax.ShapeDtypeStruct((qn, nchunks_total), jnp.int32),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel_batched, chunk=chunk, block_n=block_n),
-        grid=(nblocks,),
+    width = block_n // chunk
+    q32 = queries.astype(jnp.float32)
+    qsum = jnp.sum(q32, axis=-1, keepdims=True)
+    out_shape = (qn, 1, m // chunk)
+    sq = pl.Squeezed()
+    cmax, carg = pl.pallas_call(
+        functools.partial(_kernel, chunk=chunk, block_n=block_n),
+        grid=(qn, m // block_n),
         in_specs=[
-            pl.BlockSpec((qn, d), lambda i: (0, 0)),                  # queries
-            pl.BlockSpec((qn, 1), lambda i: (0, 0)),                  # qsum
-            pl.BlockSpec((qn, block_n, d), lambda i: (0, i, 0)),      # data
-            pl.BlockSpec((qn, block_n), lambda i: (0, i)),            # affine
-            pl.BlockSpec((qn, block_n), lambda i: (0, i)),            # scale
-            pl.BlockSpec((qn, block_n), lambda i: (0, i)),            # bias
+            pl.BlockSpec((sq, 1, d), lambda i, j: (i, 0, 0)),         # query
+            pl.BlockSpec((sq, 1, 1), lambda i, j: (i, 0, 0)),         # qsum
+            pl.BlockSpec((sq, block_n, d), lambda i, j: (i, j, 0)),   # data
+            pl.BlockSpec((sq, 3, block_n), lambda i, j: (i, 0, j)),   # terms
         ],
         out_specs=(
-            pl.BlockSpec((qn, per_block), lambda i: (0, i)),
-            pl.BlockSpec((qn, per_block), lambda i: (0, i)),
+            pl.BlockSpec((sq, 1, width), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((sq, 1, width), lambda i, j: (i, 0, j)),
         ),
-        out_shape=out_shapes,
+        out_shape=(jax.ShapeDtypeStruct(out_shape, jnp.float32),
+                   jax.ShapeDtypeStruct(out_shape, jnp.int32)),
         interpret=interpret,
-    )(queries.astype(jnp.float32), qsum, data_i8, aff, scale, bias2)
+    )(q32[:, None, :], qsum[:, None, :], data_i8, _terms(vmin, scale, bias))
+    return cmax[:, 0], carg[:, 0]

@@ -3,19 +3,32 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.ivf_topk.ivf_topk import block_rows
 
-def scan_topk_ref(queries, data_i8, vmin, scale, *, chunk: int = 128):
+
+def _chunk_reduce_ref(scores, chunk: int):
+    """(Q, N) scores -> per-chunk (max, argmax) in the kernel's strided
+    chunk layout (see ivf_topk.py) at the wrappers' default row block:
+    chunk c of block b holds the rows b·block_n + j·width + c. Rows past N
+    (block padding) read -inf."""
+    qn, n = scores.shape
+    bn = block_rows(n, chunk)
+    pad = (-n) % bn
+    sc = jnp.pad(scores, ((0, 0), (0, pad)), constant_values=-jnp.inf)
+    nb, w = (n + pad) // bn, bn // chunk
+    sc = sc.reshape(qn, nb, chunk, w)
+    smax = jnp.max(sc, axis=2).reshape(qn, nb * w)
+    j = jnp.argmax(sc, axis=2).astype(jnp.int32)                 # (Q, nb, w)
+    start = (jnp.arange(nb, dtype=jnp.int32)[:, None] * bn
+             + jnp.arange(w, dtype=jnp.int32)[None, :])
+    return smax, (start[None] + j * w).reshape(qn, nb * w)
+
+
+def scan_topk_ref(queries, data_i8, vmin, scale, *, chunk: int = 8):
     """Dequantize fully, exact scores, per-chunk (max, argmax)."""
     q = queries.astype(jnp.float32)
     e = (data_i8.astype(jnp.float32) + 128.0) * scale[:, None] + vmin[:, None]
-    scores = q @ e.T                                         # (Q, N)
-    qn, n = scores.shape
-    nchunks = n // chunk
-    sc = scores.reshape(qn, nchunks, chunk)
-    smax = jnp.max(sc, axis=-1)
-    sarg = jnp.argmax(sc, axis=-1).astype(jnp.int32) + \
-        (jnp.arange(nchunks, dtype=jnp.int32) * chunk)[None, :]
-    return smax, sarg
+    return _chunk_reduce_ref(q @ e.T, chunk)                   # (Q, N)
 
 
 def scan_topk_ref_batched(queries, data_i8, vmin, scale, *, chunk: int = 16):
@@ -24,14 +37,7 @@ def scan_topk_ref_batched(queries, data_i8, vmin, scale, *, chunk: int = 16):
     q = queries.astype(jnp.float32)
     e = ((data_i8.astype(jnp.float32) + 128.0) * scale[..., None]
          + vmin[..., None])                                  # (Q, M, d)
-    scores = jnp.einsum("qd,qmd->qm", q, e)                  # (Q, M)
-    qn, m = scores.shape
-    nchunks = m // chunk
-    sc = scores.reshape(qn, nchunks, chunk)
-    smax = jnp.max(sc, axis=-1)
-    sarg = jnp.argmax(sc, axis=-1).astype(jnp.int32) + \
-        (jnp.arange(nchunks, dtype=jnp.int32) * chunk)[None, :]
-    return smax, sarg
+    return _chunk_reduce_ref(jnp.einsum("qd,qmd->qm", q, e), chunk)
 
 
 def pad_topk(vals, ids, k: int):

@@ -24,6 +24,7 @@ import numpy as np
 import jax
 
 from repro import obs
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs import get_config, smoke_config
 from repro.core import HMGIIndex
 from repro.data.synthetic import ground_truth_topk, make_corpus, recall_at_k
@@ -47,6 +48,7 @@ def main():
     args = ap.parse_args()
     if args.recover and not args.data_dir:
         ap.error("--recover requires --data-dir")
+    enable_compile_cache()
 
     cfg = get_config("hmgi").replace(n_partitions=32, n_probe=8,
                                      kmeans_iters=8, top_k=args.k)

@@ -209,7 +209,8 @@ def _rescore(q2, vectors, rows, tombstones, sv, si, weight):
     present = jnp.logical_and(
         present, ~tombstones[jnp.clip(si, 0, tombstones.shape[0] - 1)])
     vecs = vectors[jnp.clip(rr, 0, vectors.shape[0] - 1)]       # (Q, C, d2)
-    sim2 = jnp.einsum("qd,qcd->qc", q2, vecs)
+    sim2 = jnp.einsum("qd,qcd->qc", q2, vecs,
+                      precision=jax.lax.Precision.HIGHEST)
     sim2 = jnp.where(present, sim2, 0.0)
     new = jnp.where(jnp.isfinite(sv),
                     (1.0 - weight) * sv + weight * sim2, -jnp.inf)

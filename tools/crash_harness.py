@@ -7,12 +7,15 @@ the harness:
    ``DurableHMGIIndex`` with the fault point armed via ``HMGI_FAULTPOINT``
    — the child dies with ``os._exit(137)`` (SIGKILL semantics: no flush,
    no atexit, no finally) at the durability boundary;
-2. recovers the data dir in-process and reads the recovered ``last_seq`` D;
-3. builds a *golden* index by applying the first D logged ops of the same
-   script (plus the interleaved searches that precede them — workload heat
-   must match too) to a fresh in-memory ``HMGIIndex``;
-4. asserts ``search`` and ``hybrid_search`` results are **bit-identical**
-   between recovered and golden.
+2. runs a second child (``--verify``) that recovers the data dir and reads
+   the recovered ``last_seq`` D, builds a *golden* index by applying the
+   first D logged ops of the same script (plus the interleaved searches
+   that precede them — workload heat must match too) to a fresh in-memory
+   ``HMGIIndex``, and asserts ``search`` and ``hybrid_search`` results are
+   **bit-identical** between recovered and golden.
+
+The parent process never imports JAX: on an accelerator host a parent that
+holds the device would leave the children none.
 
 ``recover.*`` points crash the *recovery* instead: the child runs clean,
 a second child dies mid-replay, and the harness asserts the next recovery
@@ -23,6 +26,7 @@ Usage:
     python tools/crash_harness.py --sweep              # every crash point
     python tools/crash_harness.py --point wal.pre_append
     python tools/crash_harness.py --child --data-dir D # (internal)
+    python tools/crash_harness.py --verify --point P --data-dir D  # (internal)
 """
 from __future__ import annotations
 
@@ -165,39 +169,46 @@ DEFAULT_HITS = {
 }
 
 
-def run_child(data_dir: str, recover_only: bool, env_point: str | None):
+def run_child(data_dir: str, *flags: str, env_point: str | None = None):
     env = dict(os.environ)
     env.pop("HMGI_FAULTPOINT", None)
     if env_point:
         env["HMGI_FAULTPOINT"] = env_point
-    cmd = [sys.executable, os.path.abspath(__file__), "--child",
+    cmd = [sys.executable, os.path.abspath(__file__), *flags,
            "--data-dir", data_dir]
-    if recover_only:
-        cmd.append("--recover-only")
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True)
-    return proc
+    return subprocess.run(cmd, env=env, capture_output=True, text=True)
 
 
 def check_point(point: str, data_dir: str, hits: int | None = None) -> str:
     """One kill-and-recover cycle for ``point``. Returns a summary line;
-    raises on any mismatch."""
-    from repro.persistence import recover
+    raises on any mismatch. Runs in the parent, which only starts
+    children and reads their exit codes."""
     hits = DEFAULT_HITS[point] if hits is None else hits
     shutil.rmtree(data_dir, ignore_errors=True)
-    cfg = make_cfg()
     if point.startswith("recover."):
-        clean = run_child(data_dir, recover_only=False, env_point=None)
+        clean = run_child(data_dir, "--child")
         if clean.returncode != 0:
             raise AssertionError(f"clean child failed:\n{clean.stderr[-2000:]}")
-        crashed = run_child(data_dir, recover_only=True,
+        crashed = run_child(data_dir, "--child", "--recover-only",
                             env_point=f"{point}:{hits}")
     else:
-        crashed = run_child(data_dir, recover_only=False,
-                            env_point=f"{point}:{hits}")
+        crashed = run_child(data_dir, "--child", env_point=f"{point}:{hits}")
     if crashed.returncode != 137:
         raise AssertionError(
             f"{point}: child exited {crashed.returncode}, expected 137 "
             f"(fault never fired?)\n{crashed.stderr[-2000:]}")
+    verified = run_child(data_dir, "--verify", "--point", point)
+    if verified.returncode != 0:
+        raise AssertionError(f"{point}: {verified.stderr[-2000:]}")
+    summary = verified.stdout.strip().splitlines()[-1]
+    return f"{point}: killed at hit {hits}, {summary}"
+
+
+def verify_recovery(point: str, data_dir: str) -> str:
+    """The ``--verify`` child: recover ``data_dir`` and hold it
+    bit-identical to the golden index at the recovered op count."""
+    from repro.persistence import recover
+    cfg = make_cfg()
     idx = recover(cfg, data_dir, seed=0)
     d = idx.last_seq
     idx.close()
@@ -208,13 +219,14 @@ def check_point(point: str, data_dir: str, hits: int | None = None) -> str:
     assert_bit_identical(idx, golden, point)
     trail = idx.metrics().get("recovery", "")
     idx.close()
-    return f"{point}: killed at hit {hits}, recovered {d} ops — OK [{trail}]"
+    return f"recovered {d} ops — OK [{trail}]"
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--child", action="store_true")
     ap.add_argument("--recover-only", action="store_true")
+    ap.add_argument("--verify", action="store_true")
     ap.add_argument("--data-dir", default="/tmp/hmgi_crash_harness")
     ap.add_argument("--point")
     ap.add_argument("--hits", type=int, default=None)
@@ -230,6 +242,12 @@ def main():
             idx = DurableHMGIIndex(cfg, args.data_dir, seed=0)
             apply_ops(idx, scripted_ops())
         idx.close()
+        return
+    if args.verify:
+        try:
+            print(verify_recovery(args.point, args.data_dir))
+        except AssertionError as e:
+            sys.exit(str(e))
         return
 
     from repro.persistence.faultpoints import POINTS

@@ -22,8 +22,6 @@ _TRANSFER_PRIMS = {"device_put", "copy_to_host_async", "io_callback",
 def _iter_eqns(jaxpr, in_pallas: bool = False) -> Iterator[Tuple[object,
                                                                  bool]]:
     """Yield (eqn, inside_pallas) over jaxpr and its sub-jaxprs."""
-    import jax
-
     for eqn in jaxpr.eqns:
         prim = eqn.primitive.name
         yield eqn, in_pallas
@@ -35,9 +33,8 @@ def _iter_eqns(jaxpr, in_pallas: bool = False) -> Iterator[Tuple[object,
 
 
 def _as_jaxprs(val):
-    import jax
+    from jax.extend import core
 
-    core = jax.core
     if isinstance(val, core.ClosedJaxpr):
         yield val.jaxpr
     elif isinstance(val, core.Jaxpr):
