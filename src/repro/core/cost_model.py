@@ -15,6 +15,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
+
 
 @dataclasses.dataclass
 class CostModel:
@@ -98,7 +100,7 @@ def estimate_selectivity(node_pass) -> float:
     """Fraction of rows a predicate admits — one mean over the (N,) mask the
     predicate compiler already produced (exact, not a sketch: attributes are
     resident on device and the mask is reused by every scan stage)."""
-    return float(np.mean(np.asarray(node_pass)))
+    return float(np.mean(obs.to_host(node_pass, "selectivity")))
 
 
 def plan_filtered_scan(selectivity: float, k: int, *, n_rows: int,

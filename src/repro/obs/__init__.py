@@ -2,9 +2,11 @@
 
 Host-side only — nothing in this package runs inside traced code. See
 ``metrics`` (counters/gauges/histograms with exact quantiles), ``spans``
-(nestable timed spans with optional ``block_until_ready`` fencing and a
-``trace()`` tree collector), and ``export`` (Prometheus text exposition,
-JSON snapshot).
+(nestable timed spans, each also a profiler annotation, with optional
+``block_until_ready`` fencing and a ``trace()`` tree collector), ``sync``
+(``to_host`` for counted device-to-host reads, and the
+``executor.compiles`` counter) and ``export`` (Prometheus text
+exposition, JSON snapshot).
 
 Typical use::
 
@@ -22,6 +24,7 @@ from .metrics import (COUNT_BUCKETS, DEFAULT_BUCKETS, Counter, Gauge,
                       set_enabled)
 from .spans import (Span, SpanNode, Trace, observe_ms, set_sync_spans, span,
                     sync_spans, trace)
+from .sync import to_host
 from .export import parse_prometheus, render_prometheus
 
 
@@ -54,6 +57,6 @@ __all__ = [
     "registry", "counter", "gauge", "histogram", "snapshot", "reset",
     "enabled", "set_enabled",
     "Span", "SpanNode", "Trace", "span", "trace", "observe_ms",
-    "set_sync_spans", "sync_spans",
+    "set_sync_spans", "sync_spans", "to_host",
     "render_prometheus", "parse_prometheus",
 ]

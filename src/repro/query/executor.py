@@ -16,6 +16,7 @@ oversample loop)."""
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Optional, Tuple
 
 import jax
@@ -38,6 +39,10 @@ from repro.query.planner import (PhysicalPlan, PRescore, PSeed, PSetOp,
                                  PTraverse)
 
 State = Tuple[jax.Array, jax.Array]      # (scores (Q, C), ids (Q, C))
+
+# the ``call`` argument of each ``query.execute`` span: in a profiler
+# trace, the spans nested inside it on its thread belong to that call
+_CALL_IDS = itertools.count()
 
 
 def _topk_state(sv: jax.Array, si: jax.Array, k: int) -> State:
@@ -105,7 +110,7 @@ def run_seed(index, s: PSeed, node_pass) -> State:
     # workload tracker and (as precomputed probes) every shard's IVF scan
     probes, _ = assign_topk(q, m.ivf.centroids, n_probe)
     if m.workload is not None:
-        m.workload.record(np.asarray(probes))
+        m.workload.record(obs.to_host(probes, "workload"))
     if node_pass is None:
         return search_raw(index, m, q, probes, n_probe, k, impl=s.impl,
                           sharded=sharded)
@@ -126,7 +131,8 @@ def run_seed(index, s: PSeed, node_pass) -> State:
         sv = jnp.where(ok, sv, -jnp.inf)
         if k_scan >= k_max:
             break
-        if int(jnp.min(jnp.sum(ok, axis=1))) >= k:
+        least = jnp.min(jnp.sum(ok, axis=1))
+        if int(obs.to_host(least, "oversample")) >= k:
             break
         k_scan = min(2 * k_scan, k_max)
     vals, ids = _topk_state(sv, si, min(k, sv.shape[1]))
@@ -291,7 +297,8 @@ def search_bucketed(index, queries, modality: str, *, k: int,
     else:
         sv, si = index.search(q, modality, k=k, n_probe=n_probe,
                               where=where, impl=impl)
-    return np.asarray(sv)[:n_q], np.asarray(si)[:n_q]
+    sv, si = obs.to_host((sv, si), "result")
+    return sv[:n_q], si[:n_q]
 
 
 # ----------------------------------------------------------------- execution
@@ -304,7 +311,7 @@ def run_topk(sv: jax.Array, si: jax.Array, k: int) -> State:
 def execute(index, phys: PhysicalPlan, *, truncate: bool = True) -> State:
     """Runs a compiled plan. truncate=False returns the last stage's full
     candidate set (the facade's rerank lane re-scores it before cutting)."""
-    with obs.span("query.execute") as root:
+    with obs.span("query.execute", call=next(_CALL_IDS)) as root:
         if isinstance(phys.source, PSetOp):
             with obs.span("query.setop") as sp:
                 sv, si = sp.fence(run_setop(index, phys.source))

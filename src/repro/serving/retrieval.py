@@ -32,6 +32,7 @@ cannot run under the token-passing interleaver).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -42,6 +43,9 @@ from repro import obs
 from repro.query.executor import search_bucketed
 from repro.serving.cache import HotResultCache
 from repro.serving.scheduler import AdmissionController
+
+# the ``batch`` argument of each ``serving.batch`` span
+_BATCH_IDS = itertools.count()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,11 +148,11 @@ class MicroBatcher:
                 else:
                     obs.counter("serving.batch.dedup_hits").inc()
                 slot.append(at)
-            sv, si = run_plan(self.index, plan, np.concatenate(rows))
+            with obs.span("serving.batch", batch=next(_BATCH_IDS),
+                          size=len(members)):
+                sv, si = run_plan(self.index, plan, np.concatenate(rows))
             obs.histogram("serving.batch_q",
                           obs.COUNT_BUCKETS).observe(len(members))
-            obs.counter("serving.batch.calls").inc()
-            obs.counter("serving.batch.queries").inc(len(members))
             for p, at in zip(members, slot):
                 p.scores, p.ids = sv[at:at + 1], si[at:at + 1]
 
@@ -176,7 +180,8 @@ class MicroBatcher:
                 self._leader = True
         if lead:
             if self.window_s > 0.0:
-                time.sleep(self.window_s)      # collect followers
+                with obs.span("serving.window"):
+                    time.sleep(self.window_s)  # collect followers
             while True:
                 with self._lock:
                     batch = self._take_batch_locked()

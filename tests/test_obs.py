@@ -286,3 +286,168 @@ def test_staticcheck_all_stays_clean():
                        cwd=repo, env=env, capture_output=True, text=True,
                        timeout=600)
     assert r.returncode == 0, f"staticcheck --all failed:\n{r.stdout}\n{r.stderr}"
+
+
+# ------------------------------------------- profiler clock, syncs, compiles
+def _host_spans(trace_dir):
+    """(name, start_ns, end_ns, args, thread) of every program span on the
+    host plane of the profiler trace written under ``trace_dir``; spans of
+    one thread share ``thread`` (the plane and line they sit on)."""
+    import glob
+    from jax.profiler import ProfileData
+    path = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("query.", "serving.")):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats), (plane.name, line.name)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def filtered_index():
+    """A small index with a ``year`` column: ``year >= 2026`` admits few
+    rows (pushdown), ``year >= 2001`` nearly all (oversample)."""
+    from repro.configs import get_config
+    from repro.core import HMGIIndex
+    rng = np.random.default_rng(11)
+    cfg = get_config("hmgi").replace(
+        modalities=("text",), n_partitions=4, n_probe=4, kmeans_iters=4,
+        top_k=5, delta_capacity=64)
+    idx = HMGIIndex(cfg, seed=0)
+    vecs = rng.normal(size=(128, cfg.dim)).astype(np.float32)
+    edges = (np.arange(128), (np.arange(128) + 1) % 128)
+    year = rng.integers(2000, 2030, 128).astype(np.int32)
+    idx.ingest({"text": (np.arange(128), vecs)}, n_nodes=128, edges=edges,
+               node_attrs={"year": year})
+    return idx, vecs
+
+
+def test_spans_sit_on_the_profiler_host_plane_with_one_call_id(
+        filtered_index, tmp_path):
+    import jax
+    idx, vecs = filtered_index
+    idx.hybrid_search(vecs[:2], "text", k=5, n_hops=1)          # compile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        idx.hybrid_search(vecs[:2], "text", k=5, n_hops=1)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(tmp_path)
+    by_name = {}
+    for name, a, b, args, thread in spans:
+        by_name.setdefault(name, []).append((a, b, args, thread))
+    execute = by_name["query.execute"]
+    assert len(execute) == 1
+    lo, hi, args, thread = execute[0]
+    assert isinstance(args["call"], int)
+    # the call's spans are those nested inside its ``query.execute`` on its
+    # thread, and carry no id of their own
+    for name in ("query.seed_scan", "query.traversal", "query.fusion"):
+        (a, b, inner, where), = by_name[name]
+        assert lo <= a and b <= hi and where == thread, name
+        assert "call" not in inner, name
+    # the planner runs before the call is numbered
+    (a, _, plan_args, where), = by_name["query.plan"]
+    assert a < lo and where == thread and "call" not in plan_args
+
+
+def test_span_arguments_reach_its_annotation_alone(tmp_path):
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("query.execute", call=41):
+            with obs.span("query.seed_scan"):
+                pass
+        with obs.span("serving.batch", batch=7, size=3):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    got = sorted((name, {k: args[k] for k in ("call", "batch", "size")
+                         if k in args})
+                 for name, _, _, args, _ in _host_spans(tmp_path))
+    assert got == [("query.execute", {"call": 41}), ("query.seed_scan", {}),
+                   ("serving.batch", {"batch": 7, "size": 3})]
+    # the annotation is opened and closed with the span's histogram
+    assert obs.histogram("serving.batch").count == 1
+
+
+def test_compile_counter_counts_jit_cache_misses():
+    import jax
+    import jax.numpy as jnp
+    x = jnp.ones((3,))
+
+    def fresh():
+        return jax.jit(lambda v: v * 2 + 1)
+
+    f = fresh()
+    f(x)
+    before = obs.counter("executor.compiles").value
+    f(x)                                          # cached: no compile
+    assert obs.counter("executor.compiles").value == before
+    fresh()(x)                                    # a new closure compiles
+    assert obs.counter("executor.compiles").value == before + 1
+
+
+def test_compile_counter_works_with_the_persistent_cache_off():
+    import jax
+    import jax.numpy as jnp
+    on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        x = jnp.ones((5,))
+        before = obs.counter("executor.compiles").value
+        jax.jit(lambda v: v - 3)(x)
+        assert obs.counter("executor.compiles").value == before + 1
+    finally:
+        jax.config.update("jax_enable_compilation_cache", on)
+
+
+def test_to_host_counts_and_spans_each_read():
+    import jax.numpy as jnp
+    a, b = obs.to_host((jnp.arange(3), jnp.ones((2,))), "result")
+    assert isinstance(a, np.ndarray) and a.tolist() == [0, 1, 2]
+    assert b.tolist() == [1.0, 1.0]
+    assert int(obs.to_host(jnp.int32(7), "oversample")) == 7
+    assert obs.counter("executor.syncs").value == 2
+    assert obs.histogram("query.to_host").count == 2
+
+
+# syncs one bucketed search makes: the result read, the workload tracker's
+# probe read, and with a ``where`` the planner's selectivity read; an
+# oversampled scan adds one read per widening round (one here: nearly every
+# row passes, so the first width holds k survivors)
+@pytest.mark.parametrize("where,mode,syncs", [
+    (None, None, 2),
+    (("year", ">=", 2026), "prefilter", 3),
+    (("year", ">=", 2001), "oversample", 4),
+])
+@pytest.mark.parametrize("n_hops", [0, 1])
+def test_syncs_per_call_follow_the_plan(filtered_index, where, mode, syncs,
+                                        n_hops):
+    from repro.query.executor import search_bucketed
+    idx, vecs = filtered_index
+    search_bucketed(idx, vecs[:3], "text", k=5, where=where, n_hops=n_hops)
+    obs.reset()
+    search_bucketed(idx, vecs[:3], "text", k=5, where=where, n_hops=n_hops)
+    assert idx.metrics().get("filter_mode") == mode or where is None
+    assert obs.counter("executor.syncs").value == syncs
+    assert obs.histogram("query.to_host").count == syncs
+
+
+def test_sync_guard_rehearsal_counts_each_plan_class(capsys):
+    """``tools/sync_guard.py`` off the TPU: every class served, with the
+    planner's mode and the syncs its plan implies (the guard itself only
+    fires on a device backend)."""
+    from tools import sync_guard
+    assert sync_guard.main(["--rehearse", "--rows", "512"]) == 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["ok"] is False                     # a rehearsal never is
+    got = {k: (v["result"], v["mode"], v["syncs"])
+           for k, v in out["calls"].items()}
+    assert got == {"pushdown": ("ok", "prefilter", 3.0),
+                   "oversample": ("ok", "oversample", 4.0),
+                   "vector": ("ok", None, 2.0)}

@@ -160,6 +160,59 @@ class TestMicroBatcher:
         assert h.count >= 1
         assert h.total / h.count > 1.0, "no cross-request batch formed"
 
+    def test_batch_and_window_spans_once_per_micro_batch(self, setup,
+                                                         tmp_path):
+        """Each micro-batch records one ``serving.window`` (the leader's
+        wait for followers) and one ``serving.batch`` (its bucketed call),
+        and the batch's annotation carries its id and size."""
+        import glob
+        import jax
+        from jax.profiler import ProfileData
+        idx, queries = setup
+        plan = RetrievalPlan(modality="text", k=K)
+        mb = MicroBatcher(idx, window_s=0.001, max_batch=64)
+        mb.search(plan, queries[:1])                           # compile
+        obs.reset()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for i in range(3):
+                mb.search(plan, queries[i:i + 1])
+        finally:
+            jax.profiler.stop_trace()
+        assert obs.histogram("serving.window").count == 3
+        assert obs.histogram("serving.batch").count == 3
+        assert obs.histogram("serving.batch_q").count == 3
+        path = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)[-1]
+        notes = [(ev.name, dict(ev.stats))
+                 for plane in ProfileData.from_file(path).planes
+                 for line in plane.lines for ev in line.events
+                 if ev.name.startswith("serving.")]
+        assert sorted(n for n, _ in notes) == ["serving.batch"] * 3 + [
+            "serving.window"] * 3
+        batches = [a for n, a in notes if n == "serving.batch"]
+        assert [a["size"] for a in batches] == [1, 1, 1]
+        assert len({a["batch"] for a in batches}) == 3
+
+        # concurrent riders: still one window and one call per batch
+        obs.reset()
+        mb = MicroBatcher(idx, window_s=0.05, max_batch=64)
+        barrier = threading.Barrier(8)
+
+        def worker(i):
+            barrier.wait()
+            mb.search(plan, queries[i:i + 1])
+
+        threads = [threading.Thread(target=worker, args=(i,))
+                   for i in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        n_batches = obs.histogram("serving.batch_q").count
+        assert 1 <= n_batches < 8
+        assert obs.histogram("serving.batch").count == n_batches
+        assert obs.histogram("serving.window").count == n_batches
+
     def test_mixed_plan_batch_falls_back_per_group(self, setup):
         """Two plans in one window: each group runs its own bucketed call
         and every rider still gets its own plan's solo bytes."""
