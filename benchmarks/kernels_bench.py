@@ -5,11 +5,11 @@ arithmetic-intensity accounting).
 
 ``ivf_probe_*`` is the exception: it times the two *production* probe paths
 of ``ivf.search`` against each other on identical shapes — the fused-kernel
-slab scan (int8 end-to-end) vs the legacy fp32 gather-dequant einsum. The
-kernel path wins even under interpret mode because it never materialises the
-(qb, P, cap, d) fp32 dequant and replaces the full-width top-k with a
-chunk-survivor top-k + tiny rescore; on TPU the HBM saving (×4 on traffic)
-dominates."""
+scan of the slab in place (int8 end-to-end, each probed partition read once
+per call) vs the legacy fp32 gather-dequant einsum. The kernel path never
+materialises the (qb, P, cap, d) fp32 dequant nor any copy of the probed
+rows, and replaces the full-width top-k with a chunk-survivor top-k + tiny
+rescore."""
 from __future__ import annotations
 
 import numpy as np
@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import timeit
 from repro.core import ivf as ivf_mod
+from repro.core import partitioner
 from repro.core.quantization import quantize
 from repro.kernels.ivf_topk.ops import scan_topk_quantized
 from repro.kernels.ivf_topk.ref import scan_topk_ref, topk_from_chunks
@@ -43,11 +44,13 @@ def run(report):
                                         impl="kernel"), trials=3)
     m_rows = n_probe * idx.capacity
     fp32_interm = nq * m_rows * d * 4          # the einsum path's HBM dequant
+    probes, _ = partitioner.assign_topk(q, idx.centroids, n_probe)
+    slab_read = len(np.unique(np.asarray(probes))) * idx.capacity * d
     report("ivf_probe_einsum", t_e * 1e6,
            f"fp32_dequant_bytes={fp32_interm:.2e}")
     report("ivf_probe_kernel", t_k * 1e6,
            f"speedup={t_e / t_k:.2f}x fp32_dequant_bytes=0 "
-           f"int8_scan_bytes={nq * m_rows * d:.2e}")
+           f"int8_slab_bytes_read={slab_read:.2e}")
 
     # ivf_topk: HBM bytes per query at int8 vs bf16 storage
     n, d, q = 8192, 128, 64

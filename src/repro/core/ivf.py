@@ -9,18 +9,20 @@ on the MXU.
 Storage is fixed-shape: (K, cap, d) quantized buckets + (K, cap) ids with -1
 sentinels, so search jits once per (K, cap, n_probe, k) and shards cleanly.
 
-Slab layout & the fused kernel. ``IVFIndex.slab_view`` exposes the buckets as
-one flattened (K·cap, d) int8 slab with per-row vmin/scale and -1 ids on
-empty slots; partition ``p`` is the contiguous row block
-[p·cap, (p+1)·cap). The probe path gathers each query's probed blocks
-(int8 — never dequantized in HBM) and hands them to the fused Pallas kernel
-(``kernels/ivf_topk``), which folds the affine dequant into the scan matmul
-and reduces to per-chunk survivors; an exact rescore of the top-k chunks
-recovers the exact top-k. ``impl`` selects the path: "kernel" (int8 indexes),
-"einsum" (the legacy fp32 dequant-then-einsum, kept for 4/16-bit storage and
-as the benchmark baseline), or "auto" (kernel whenever bits == 8). Off-TPU
-the kernel runs under ``interpret=True``, probed once on the first kernel
-call (see ``kernels/ivf_topk/ops._interpret_mode``).
+Slab layout & the fused kernel. The buckets are one (K, cap, d) int8 slab
+with per-row vmin/scale and -1 ids on empty slots (``IVFIndex.slab_view``
+flattens it to (K·cap, d) rows: partition ``p`` is [p·cap, (p+1)·cap)).
+The probe path never copies the probed partitions: it sorts the call's
+(query, probe) pairs by partition and hands the slab itself to the fused
+Pallas kernel (``kernels/ivf_topk``), which reads each probed partition's
+row blocks in place, once per call, and scores them against every query
+that probes it; the affine dequant folds into the scan matmul and each
+chunk reduces to one survivor, and an exact rescore of each query's top-k
+chunks recovers the exact top-k. ``impl`` selects the path: "kernel" (int8
+indexes), "einsum" (the legacy fp32 dequant-then-einsum, kept for 4/16-bit
+storage and as the test oracle), or "auto" (kernel whenever bits == 8).
+Off-TPU the kernel runs under ``interpret=True``, probed once on the first
+kernel call (see ``kernels/ivf_topk/ops._interpret_mode``).
 
 Sharded execution path. ``shard_index`` re-lays the stable slab out as S
 per-shard replicas with a leading shard dim: partition ``p``'s capacity slots
@@ -50,26 +52,22 @@ from repro.common.shapes import pad_to_chunk
 from repro.core import partitioner
 from repro.core.graph_store import mask_pass
 from repro.core.quantization import QuantizedVectors, quantize
-from repro.kernels.ivf_topk.ivf_topk import block_rows
-from repro.kernels.ivf_topk.ops import (_interpret_mode,
-                                        scan_topk_quantized_batched)
+from repro.kernels.ivf_topk.ops import scan_topk_probed
 from repro.kernels.ivf_topk.ref import pad_topk
 
-# probe-path kernel tiling: chunk-of-16 survivors (see
-# kernels/ivf_topk/ivf_topk.py for the layout and VMEM accounting)
-_CHUNK = 16
+
+# Partition capacities are whole multiples of the int8 memory tile's rows.
+# On TPU a (K, cap, d) int8 slab with cap off that tile is laid out
+# slot-major (that layout needs no padding), so no partition's rows would
+# be contiguous, and the probe kernel's in-place block reads would cost a
+# relayout copy of the whole slab on every call.
+SLOT_TILE = 32
 
 
-def _probe_block_n(m: int) -> int:
-    """Row-block size for the probe scan of an m-row per-query slab. On TPU
-    it is the kernel's default (128 survivor lanes × chunk = 2048 rows: a
-    lane-dense output tile, and ~2·2048·d int8 bytes of double-buffered
-    VMEM). Under the interpreter each grid step costs fixed overhead and
-    padding to a block multiple is pure waste, so the whole per-query slab
-    runs as one step, padded only to the chunk size."""
-    if _interpret_mode():
-        return pad_to_chunk(m, _CHUNK)
-    return block_rows(m, _CHUNK)
+def default_capacity(n: int, n_partitions: int) -> int:
+    """Slots per partition for n rows: twice the mean fill, plus one, to
+    a whole ``SLOT_TILE``."""
+    return pad_to_chunk(max(int(2 * n / n_partitions) + 1, 8), SLOT_TILE)
 
 
 @functools.partial(
@@ -121,7 +119,7 @@ def build(key, vectors: jax.Array, ids: jax.Array, *, n_partitions: int,
     fit their partition's capacity and belong in the delta store."""
     n, d = vectors.shape
     k = n_partitions
-    cap = capacity or max(int(2 * n / k) + 1, 8)
+    cap = capacity or default_capacity(n, k)
     if centroids is None:
         st = partitioner.fit(key, vectors, k, kmeans_iters)
         centroids = st.centroids
@@ -233,10 +231,13 @@ def search(index: IVFIndex, queries: jax.Array, *, n_probe: int, k: int,
            node_pass: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
     """Returns (scores (Q, k), ids (Q, k)) — dot-product similarity, descending.
 
-    impl="kernel" (default for int8) scans the probed slab blocks with the
-    fused Pallas kernel: int8 rows all the way into the scoring matmul, no
-    (qb, P, cap, d) fp32 dequant ever materialised in HBM. impl="einsum" is
-    the legacy gather-dequant-einsum path (4/16-bit storage, baseline).
+    impl="kernel" (default for int8) scans the probed partitions in place
+    with the fused Pallas kernel, partition-major over the whole call
+    (``ops.scan_topk_probed``): int8 rows all the way into the scoring
+    matmul, no copy of the probed rows and no fp32 dequant in HBM.
+    impl="einsum" is the legacy gather-dequant-einsum path (4/16-bit
+    storage, the test oracle); ``query_block`` bounds its per-block
+    (qb, P, cap, d) dequant and plays no part in the kernel path.
 
     probes: optional precomputed (Q, n_probe) partition assignment (the
     facade records workload stats from the same ``assign_topk`` — passing it
@@ -254,20 +255,6 @@ def search(index: IVFIndex, queries: jax.Array, *, n_probe: int, k: int,
         probe, _ = partitioner.assign_topk(q, index.centroids, n_probe)  # (Q, P)
     else:
         probe = probes[:, :n_probe].astype(jnp.int32)
-    cap = index.capacity
-
-    qb = min(query_block, nq)
-    pad = (-nq) % qb
-    qp = jnp.pad(q, ((0, pad), (0, 0)))
-    pp = jnp.pad(probe, ((0, pad), (0, 0)))
-    nblocks = qp.shape[0] // qb
-    # per-query probe slab: n_probe whole partitions as (partition, slot)
-    # pairs (see ``_part_slot``), padded with slot -1 to a whole number of
-    # kernel blocks (padding the gathered int8 slab afterwards would copy
-    # it a second time)
-    m = probe.shape[1] * cap
-    block_n = _probe_block_n(m)
-    m_pad = pad_to_chunk(m, block_n)
 
     def _row_valid(bids):
         """Slot occupancy ∧ predicate pushdown (pre-top-k filtering)."""
@@ -275,26 +262,20 @@ def search(index: IVFIndex, queries: jax.Array, *, n_probe: int, k: int,
             return mask_pass(node_pass, bids)
         return bids >= 0
 
-    def block_kernel(carry, i):
-        qs = jax.lax.dynamic_slice_in_dim(qp, i * qb, qb, axis=0)      # (qb, d)
-        ps = jax.lax.dynamic_slice_in_dim(pp, i * qb, qb, axis=0)      # (qb, P)
-        part = jnp.pad(jnp.repeat(ps, cap, axis=1), ((0, 0), (0, m_pad - m)))
-        slot = jnp.pad(jnp.tile(jnp.arange(cap, dtype=jnp.int32),
-                                (qb, probe.shape[1])),
-                       ((0, 0), (0, m_pad - m)), constant_values=-1)    # (qb, M)
-        s0 = jnp.maximum(slot, 0)
-        bdata = index.data[part, s0]                                    # int8!
-        bmin = index.vmin[part, s0]
-        bscale = index.scale[part, s0]
-        bids = jnp.where(slot >= 0, index.ids[part, s0], -1)            # (qb, M)
-        vals, pos = scan_topk_quantized_batched(
-            qs, bdata, bmin, bscale, _row_valid(bids), k=k,
-            chunk=_CHUNK, block_n=block_n)
-        ids = jnp.where(pos >= 0,
-                        jnp.take_along_axis(
-                            bids, jnp.clip(pos, 0, m_pad - 1), axis=1),
-                        -1)
-        return carry, (vals, ids)
+    if impl == "kernel":
+        # the whole call at once, partition-major: the slab is read in
+        # place, each probed partition once per row block
+        vals, part, slot = scan_topk_probed(
+            q, index.data, index.vmin, index.scale, _row_valid(index.ids),
+            probe, k=k)
+        ids = jnp.where(slot >= 0, index.ids[part, jnp.maximum(slot, 0)], -1)
+        return vals, ids
+
+    qb = min(query_block, nq)
+    pad = (-nq) % qb
+    qp = jnp.pad(q, ((0, pad), (0, 0)))
+    pp = jnp.pad(probe, ((0, pad), (0, 0)))
+    nblocks = qp.shape[0] // qb
 
     def block_einsum(carry, i):
         qs = jax.lax.dynamic_slice_in_dim(qp, i * qb, qb, axis=0)      # (qb, d)
@@ -314,8 +295,7 @@ def search(index: IVFIndex, queries: jax.Array, *, n_probe: int, k: int,
                         jnp.take_along_axis(fids, pos, axis=1), -1)
         return carry, pad_topk(vals, ids, k)
 
-    block = block_kernel if impl == "kernel" else block_einsum
-    _, (vals, ids) = jax.lax.scan(block, None, jnp.arange(nblocks))
+    _, (vals, ids) = jax.lax.scan(block_einsum, None, jnp.arange(nblocks))
     return vals.reshape(-1, k)[:nq], ids.reshape(-1, k)[:nq]
 
 
@@ -373,7 +353,7 @@ def shard_index(index: IVFIndex, n_shards: int) -> IVFIndex:
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")
     k, cap = index.ids.shape
-    cap_l = (cap + n_shards - 1) // n_shards
+    cap_l = pad_to_chunk(-(-cap // n_shards), SLOT_TILE)
     pad = n_shards * cap_l - cap
 
     def deal(a, fill):

@@ -11,12 +11,12 @@ app-level JAX setup (``jax.distributed.initialize``) still runs first.
                      (the paper's ANNS hot loop; ScaNN-on-TPU layout).
                      Two entry points: ``scan_topk_quantized`` scans one
                      corpus slab shared by all queries (delta store,
-                     monolithic baseline); ``scan_topk_quantized_batched``
-                     scans per-query slabs — the IVF probe path gathers each
-                     query's probed partitions (whole (cap, d) blocks of the
-                     (K, cap, d) index slab) and rescores the top-k chunk
-                     survivors exactly. int8 rows never dequantize to fp32
-                     in HBM on either path.
+                     monolithic baseline); ``scan_topk_probed`` is the IVF
+                     probe path — it reads the (K, cap, d) index slab in
+                     place, partition-major, each probed partition once per
+                     call against every query that probes it, and rescores
+                     each query's top-k chunk survivors exactly. int8 rows
+                     never dequantize to fp32 in HBM on either path.
   segment_reduce   — one-hot-matmul segment sum (GNN message passing,
                      EmbeddingBag reduce; MXU-friendly scatter replacement)
   decode_attention — GQA single-token flash-decode with online softmax

@@ -1,2 +1,1 @@
-from repro.kernels.ivf_topk.ops import (scan_topk_quantized,
-                                        scan_topk_quantized_batched)
+from repro.kernels.ivf_topk.ops import scan_topk_probed, scan_topk_quantized

@@ -33,11 +33,21 @@ Two entry points share the kernel math:
   scan_topk_pallas         — one corpus slab shared by every query (the
                              delta-store scan, monolithic baselines). Grid
                              (query blocks, row blocks).
-  scan_topk_pallas_batched — per-query slabs (Q, M, d): the IVF probe path,
-                             where each query gathered its own probed
-                             partitions as contiguous row blocks of the
-                             flattened (K·cap, d) index slab. Grid
-                             (queries, row blocks), one query per step.
+  scan_topk_pallas_grouped — the IVF probe path, partition-major: the
+                             (K, cap, d) index slab is read in place, and
+                             each grid step scores one partition's row
+                             block against a group of the queries that
+                             probe it (one (G, d)·(d, width) matmul per
+                             strided slab). The partition of each group is
+                             a scalar prefetch that the data ``index_map``
+                             reads, so no copy of the probed partitions is
+                             ever made. Grid (row blocks, groups), groups
+                             innermost and sorted by partition: consecutive
+                             groups of one partition map to the same data
+                             block, which is then fetched from HBM once per
+                             call, not once per query. ``cap`` need not be
+                             a multiple of the row block: rows past it in
+                             the partial last block are masked in-kernel.
 """
 from __future__ import annotations
 
@@ -46,8 +56,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128                        # TPU vreg lane width
+NEG = -3e38                        # mask score (sign-safe, unlike -inf)
 _BLOCK_Q = 256                     # query rows per block of the flat kernel
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -69,10 +81,14 @@ def chunk_rows(cpos, chunk: int, block_n: int):
     return rows.reshape(*cpos.shape[:-1], -1)
 
 
-def _scan_block(q, qsum, d_ref, t_ref, chunk: int, width: int, base):
+def _scan_block(q, qsum, d_ref, t_ref, chunk: int, width: int, base,
+                n_rows: int | None = None):
     """(rows, width) running (max, argmax) over the block's ``chunk``
     strided slabs. q (rows, d) fp32; qsum (rows, 1); t_ref (3, block_n)
-    fp32 = (affine, scale, bias) per row; base = the block's first row."""
+    fp32 = (affine, scale, bias) per row; base = the block's first row.
+    Rows at or past ``n_rows`` (a partial last block: its data and terms
+    there are undefined) score ``NEG``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], width), 1)
     best = arg = None
     for j in range(chunk):
         lo, hi = j * width, (j + 1) * width
@@ -82,13 +98,14 @@ def _scan_block(q, qsum, d_ref, t_ref, chunk: int, width: int, base):
                                    preferred_element_type=jnp.float32)
         aff, scale, bias = (t_ref[i:i + 1, lo:hi] for i in range(3))
         s = dots * scale + qsum * aff + bias                           # (r, W)
+        if n_rows is not None:
+            s = jnp.where(base + lo + lane < n_rows, s, NEG)
         if best is None:
             best, arg = s, jnp.zeros(s.shape, jnp.int32)
         else:
             upd = s > best                 # strict: ties keep the lower row
             best = jnp.where(upd, s, best)
             arg = jnp.where(upd, j, arg)
-    lane = jax.lax.broadcasted_iota(jnp.int32, best.shape, 1)
     return best, base + arg * width + lane
 
 
@@ -96,7 +113,6 @@ def _kernel(q_ref, qsum_ref, d_ref, t_ref, smax_ref, sarg_ref, *,
             chunk: int, block_n: int):
     # q_ref (bq, d) fp32 · qsum_ref (bq, 1) · d_ref (bn, d) int8 ·
     # t_ref (3, bn) fp32 (affine, scale, bias) · outputs (bq, bn/chunk).
-    # The batched variant runs it with bq = 1 on that query's own rows.
     base = pl.program_id(1) * block_n
     smax, sarg = _scan_block(q_ref[...], qsum_ref[...], d_ref, t_ref,
                              chunk, block_n // chunk, base)
@@ -149,36 +165,65 @@ def scan_topk_pallas(queries, data_i8, vmin, scale, bias=None, *,
     return cmax[:qn], carg[:qn]
 
 
-def scan_topk_pallas_batched(queries, data_i8, vmin, scale, bias=None, *,
-                             chunk: int = 16, block_n: int = 2048,
+def _grouped_kernel(gp_ref, nl_ref, q_ref, qsum_ref, d_ref, t_ref, smax_ref,
+                    *, chunk: int, block_n: int, cap: int):
+    # gp_ref (n_groups,) partition of each group · nl_ref (1,) live groups
+    # (scalar prefetch) · q_ref (G, d) fp32 · qsum_ref (G, 1) · d_ref
+    # (bn, d) int8 of the group's partition · t_ref (3, bn) · smax_ref
+    # (G, bn/chunk). Groups past the live ones hold no query: skipped.
+    base = pl.program_id(0) * block_n
+
+    @pl.when(pl.program_id(1) < nl_ref[0])
+    def _():
+        smax, _ = _scan_block(q_ref[...], qsum_ref[...], d_ref, t_ref,
+                              chunk, block_n // chunk, base, n_rows=cap)
+        smax_ref[...] = smax
+
+
+def scan_topk_pallas_grouped(group_part, n_live, queries, qsum, data_i8,
+                             terms, *, chunk: int = 16, block_n: int = 2048,
                              interpret: bool = False):
-    """Per-query-slab variant: queries (Q, d) fp32; data_i8 (Q, M, d) int8
-    (centered at -128); vmin/scale/bias (Q, M) fp32; M a multiple of
-    block_n. Returns (chunk_max (Q, M/chunk), chunk_arg) — chunk_arg
-    indexes rows of each query's own slab, strided chunk layout."""
-    qn, d = queries.shape
-    m = data_i8.shape[1]
-    assert m % block_n == 0 and block_n % chunk == 0, (m, block_n, chunk)
+    """Partition-major probe scan over the index slab, read in place.
+
+    group_part (n_groups,) int32: the partition each query group scans,
+    sorted; n_live (1,) int32: groups that hold queries (the rest trail
+    and are skipped); queries (n_groups, G, d) fp32, zero rows for
+    padding; qsum (n_groups, G, 1) their row sums; data_i8 (K, cap, d)
+    int8 (centered at -128); terms (K, 3, cap) fp32 per-row (affine,
+    scale, bias) as ``_terms`` stacks them. Returns chunk_max
+    (n_groups, G, ceil(cap/block_n)·width) in the strided chunk layout of
+    each partition (module docstring); rows of trailing groups are
+    undefined."""
+    n_groups, g, d = queries.shape
+    cap = data_i8.shape[1]
+    assert block_n % chunk == 0, (block_n, chunk)
     width = block_n // chunk
-    q32 = queries.astype(jnp.float32)
-    qsum = jnp.sum(q32, axis=-1, keepdims=True)
-    out_shape = (qn, 1, m // chunk)
+    nb = -(-cap // block_n)
     sq = pl.Squeezed()
-    cmax, carg = pl.pallas_call(
-        functools.partial(_kernel, chunk=chunk, block_n=block_n),
-        grid=(qn, m // block_n),
+
+    def live(i, nl):
+        # trailing groups revisit the last live group's blocks: no DMA
+        return jnp.minimum(i, nl[0] - 1)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(nb, n_groups),
         in_specs=[
-            pl.BlockSpec((sq, 1, d), lambda i, j: (i, 0, 0)),         # query
-            pl.BlockSpec((sq, 1, 1), lambda i, j: (i, 0, 0)),         # qsum
-            pl.BlockSpec((sq, block_n, d), lambda i, j: (i, j, 0)),   # data
-            pl.BlockSpec((sq, 3, block_n), lambda i, j: (i, 0, j)),   # terms
+            pl.BlockSpec((sq, g, d), lambda j, i, gp, nl: (live(i, nl), 0, 0)),
+            pl.BlockSpec((sq, g, 1), lambda j, i, gp, nl: (live(i, nl), 0, 0)),
+            pl.BlockSpec((sq, block_n, d),
+                         lambda j, i, gp, nl: (gp[live(i, nl)], j, 0)),
+            pl.BlockSpec((sq, 3, block_n),
+                         lambda j, i, gp, nl: (gp[live(i, nl)], 0, j)),
         ],
-        out_specs=(
-            pl.BlockSpec((sq, 1, width), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((sq, 1, width), lambda i, j: (i, 0, j)),
-        ),
-        out_shape=(jax.ShapeDtypeStruct(out_shape, jnp.float32),
-                   jax.ShapeDtypeStruct(out_shape, jnp.int32)),
+        out_specs=pl.BlockSpec((sq, g, width),
+                               lambda j, i, gp, nl: (live(i, nl), 0, j)),
+    )
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, chunk=chunk, block_n=block_n,
+                          cap=cap),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_groups, g, nb * width),
+                                       jnp.float32),
         interpret=interpret,
-    )(q32[:, None, :], qsum[:, None, :], data_i8, _terms(vmin, scale, bias))
-    return cmax[:, 0], carg[:, 0]
+    )(group_part, n_live, queries, qsum, data_i8, terms)

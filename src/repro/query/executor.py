@@ -110,7 +110,13 @@ def run_seed(index, s: PSeed, node_pass) -> State:
     # workload tracker and (as precomputed probes) every shard's IVF scan
     probes, _ = assign_topk(q, m.ivf.centroids, n_probe)
     if m.workload is not None:
-        m.workload.record(obs.to_host(probes, "workload"))
+        host_probes = obs.to_host(probes, "workload")
+        m.workload.record(host_probes)
+        # the sharing the partition-major scan finds: (query, probe) pairs
+        # per distinct probed partition, from the copy the tracker reads
+        obs.histogram("seed_scan.queries_per_partition",
+                      obs.COUNT_BUCKETS).observe(
+            host_probes.size / np.unique(host_probes).size)
     if node_pass is None:
         return search_raw(index, m, q, probes, n_probe, k, impl=s.impl,
                           sharded=sharded)

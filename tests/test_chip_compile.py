@@ -5,7 +5,7 @@ that is described, not attached, and refuses what the chip would refuse —
 block shapes off the (8, 128) tiling, more VMEM than a kernel may use, a
 program that does not fit the device. Shapes are the serve_1m deployment
 (configs/hmgi.py): 1,048,576 × 384 int8 rows, K = 64, n_probe = 8,
-top_k = 10, delta capacity 4096, query batch 256, query block 64.
+top_k = 10, delta capacity 4096, query batch 256.
 
 The topology is described inside a module fixture (never at import: only
 one process may load the TPU library at a time), and the tests skip where
@@ -21,16 +21,16 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.common.shapes import pad_to_chunk
 from repro.core import delta as delta_mod
 from repro.core import ivf as ivf_mod
 from repro.kernels.ivf_topk import ops as ivf_ops
-from repro.kernels.ivf_topk.ivf_topk import scan_topk_pallas_batched
+from repro.kernels.ivf_topk.ivf_topk import scan_topk_pallas_grouped
 
 N, D, K, N_PROBE, TOP_K = 1 << 20, 384, 64, 8, 10
-CAP = 2 * N // K + 1                       # ivf.build's default capacity
-DELTA_CAP, BATCH, QUERY_BLOCK = 4096, 256, 64
+CAP = ivf_mod.default_capacity(N, K)       # ivf.build's default capacity
+DELTA_CAP, BATCH = 4096, 256
 HBM_BYTES = 16 * 10**9                     # one v5e chip
+PROBE_TEMP_BYTES = 2 * 10**8               # a quarter of one slab copy
 
 
 @pytest.fixture(scope="module")
@@ -58,7 +58,6 @@ def compiled_for_tpu(topo):
     from jax.experimental.compilation_cache import compilation_cache
     mp = pytest.MonkeyPatch()
     mp.setattr(ivf_ops, "_interpret_mode", lambda: False)
-    mp.setattr(ivf_mod, "_interpret_mode", lambda: False)
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -88,19 +87,23 @@ def _index_shapes(sharding):
         s((K, CAP), jnp.int32), s((K,), jnp.int32), 8)
 
 
-def test_probe_kernel_serve_1m(one_chip, compiled_for_tpu):
-    """One query block's probe scan: 64 queries × n_probe·cap rows, padded
-    to the TPU row block as ivf.search pads it."""
-    block_n = ivf_mod._probe_block_n(N_PROBE * CAP)
-    m = pad_to_chunk(N_PROBE * CAP, block_n)
+@pytest.mark.parametrize("q", [BATCH, 1])
+def test_probe_kernel_serve_1m(one_chip, compiled_for_tpu, q):
+    """The partition-major probe scan over the whole slab, read in place:
+    q queries' (query, probe) pairs in groups as ``ops.scan_topk_probed``
+    cuts them, at the TPU row block."""
+    g = ivf_ops.group_rows(q * N_PROBE, K)
+    groups = jax.eval_shape(
+        lambda p: ivf_ops.probe_groups(p, K, g),
+        jax.ShapeDtypeStruct((q, N_PROBE), jnp.int32))
+    n_groups = groups[0].shape[0]
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    c = _compile(functools.partial(scan_topk_pallas_batched, chunk=16,
-                                   block_n=block_n),
-                 s((QUERY_BLOCK, D), jnp.float32),
-                 s((QUERY_BLOCK, m, D), jnp.int8),
-                 s((QUERY_BLOCK, m), jnp.float32),
-                 s((QUERY_BLOCK, m), jnp.float32),
-                 s((QUERY_BLOCK, m), jnp.float32))
+    c = _compile(functools.partial(scan_topk_pallas_grouped, chunk=16,
+                                   block_n=2048),
+                 s((n_groups,), jnp.int32), s((1,), jnp.int32),
+                 s((n_groups, g, D), jnp.float32),
+                 s((n_groups, g, 1), jnp.float32),
+                 s((K, CAP, D), jnp.int8), s((K, 3, CAP), jnp.float32))
     assert "tpu_custom_call" in c.as_text()
     ok, used = _fits(c)
     assert ok, used
@@ -122,14 +125,17 @@ def test_delta_scan_kernel(one_chip, compiled_for_tpu, q):
 
 
 def test_ivf_search_step(one_chip, compiled_for_tpu):
-    """The jitted stable scan at the serving batch, query block 64."""
+    """The jitted stable scan at the serving batch. It reads the slab in
+    place: its temporaries stay far under one gathered copy of the probed
+    partitions (6.4 GB per 64 queries) or a relayout of the slab (0.8 GB)."""
     q = jax.ShapeDtypeStruct((BATCH, D), jnp.float32, sharding=one_chip)
-    c = _compile(functools.partial(ivf_mod.search, n_probe=N_PROBE, k=TOP_K,
-                                   query_block=QUERY_BLOCK),
+    c = _compile(functools.partial(ivf_mod.search, n_probe=N_PROBE, k=TOP_K),
                  _index_shapes(one_chip), q)
     assert "tpu_custom_call" in c.as_text()
     ok, used = _fits(c)
     assert ok, used
+    temp = c.memory_analysis().temp_size_in_bytes
+    assert temp < PROBE_TEMP_BYTES, temp
 
 
 def test_sharded_search_step(topo, compiled_for_tpu):

@@ -19,8 +19,8 @@ from repro.core import index as index_mod
 from repro.core import ivf as ivf_mod
 from repro.core.fusion import FusionWeights, fuse_topk, fuse_topk_sparse
 from repro.core.quantization import quantize
-from repro.kernels.ivf_topk.ops import scan_topk_quantized_batched
-from repro.kernels.ivf_topk.ref import scan_topk_ref_batched, topk_from_chunks
+from repro.kernels.ivf_topk import ops as ivf_ops
+from repro.kernels.ivf_topk.ref import scan_probed_ref
 
 
 def _corpus(rng, n, d):
@@ -71,24 +71,40 @@ class TestKernelProbeParity:
             assert len(set(live.tolist())) == len(live)     # no dupes
             assert np.all(np.isneginf(row_s[row_i < 0]))    # dead ⇒ -inf
 
-    def test_batched_kernel_matches_ref(self, rng):
-        qn, m, d = 6, 512, 64
-        v = rng.normal(size=(qn * m, d)).astype(np.float32)
+    @pytest.mark.parametrize("qn,k_parts,cap,n_probe,block_n,skew", [
+        (6, 4, 512, 2, 128, False),      # whole blocks
+        (7, 8, 300, 3, 128, False),      # partial last block (44 of 128)
+        (5, 3, 260, 2, 128, False),      # last block's rows short of a slab
+        (40, 5, 96, 1, 96, True),        # one partition, 5 groups of 8
+        (3, 6, 64, 2, 64, False),        # most partitions unprobed
+    ])
+    def test_probed_kernel_matches_ref(self, rng, qn, k_parts, cap, n_probe,
+                                       block_n, skew):
+        """Stage 1 of the probe path — pairs grouped partition-major, the
+        slab read in place, survivors put back per (query, probe) — gives
+        the oracle's chunk maxima for every pair; dead and past-cap rows
+        read NEG in both."""
+        d, chunk = 64, 16
+        v = rng.normal(size=(k_parts * cap, d)).astype(np.float32)
         qv = quantize(jnp.asarray(v), 8)
-        data = qv.data.reshape(qn, m, d)
-        vmin = qv.vmin[:, 0].reshape(qn, m)
-        scale = qv.scale[:, 0].reshape(qn, m)
+        data = qv.data.reshape(k_parts, cap, d)
+        vmin = qv.vmin[:, 0].reshape(k_parts, cap)
+        scale = qv.scale[:, 0].reshape(k_parts, cap)
+        valid = jnp.asarray(rng.random((k_parts, cap)) < 0.7)
         queries = jnp.asarray(rng.normal(size=(qn, d)).astype(np.float32))
-        cm, ca = scan_topk_ref_batched(queries, data, vmin, scale, chunk=16)
-        rv, ri = topk_from_chunks(cm, ca, 8)
-        kv, ki = scan_topk_quantized_batched(
-            queries, data, vmin, scale, jnp.ones((qn, m), bool), k=8, chunk=16)
-        # the wrapper rescores top chunks exactly, so it can only be ≥ the
-        # one-survivor-per-chunk oracle; top-1 must agree bit-for-bit
-        np.testing.assert_allclose(np.asarray(kv[:, 0]), np.asarray(rv[:, 0]),
+        if skew:
+            probes = jnp.zeros((qn, n_probe), jnp.int32)
+        else:
+            probes = jnp.asarray(np.stack([
+                rng.choice(min(k_parts, 2 * n_probe), n_probe, replace=False)
+                for _ in range(qn)]).astype(np.int32))
+        got = ivf_ops.probe_chunk_max(queries, data, vmin, scale, valid,
+                                      probes, chunk=chunk, block_n=block_n,
+                                      interpret=True)
+        want = scan_probed_ref(queries, data, vmin, scale, valid, probes,
+                               chunk=chunk, block_n=block_n)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
-        assert np.array_equal(np.asarray(ki[:, 0]), np.asarray(ri[:, 0]))
-        assert bool(jnp.all(kv[:, :-1] >= kv[:, 1:]))       # descending
 
     def test_delta_scan_matches_brute_force(self, rng):
         """Quantized delta scan + fp32 rescore == exact brute force (the
@@ -129,6 +145,158 @@ class TestKernelProbeParity:
         store = delta_mod.delete(store, jnp.asarray([0, 3]))
         _, di = delta_mod._scan_delta(store, jnp.asarray(v), k=8)
         assert not np.any(np.isin(np.asarray(di), [0, 3]))
+
+
+def _quantized_oracle(idx, q, probes, k, visible=None):
+    """Exact top-k over the quantized rows each query probes (float64,
+    host): (scores (Q, k) -inf padded, per-query {id: score} of every
+    candidate)."""
+    ids = np.asarray(idx.ids)
+    e = ((np.asarray(idx.data, np.float64) + 128.0)
+         * np.asarray(idx.scale, np.float64)[..., None]
+         + np.asarray(idx.vmin, np.float64)[..., None])
+    ok = ids >= 0
+    if visible is not None:
+        ok &= np.asarray(visible)[np.maximum(ids, 0)]
+    top, cands = [], []
+    for qi, pr in zip(np.asarray(q, np.float64), np.asarray(probes)):
+        sc = np.einsum("pcd,d->pc", e[pr], qi)[ok[pr]]
+        cands.append(dict(zip(ids[pr][ok[pr]].tolist(), sc.tolist())))
+        row = np.full(k, -np.inf)
+        best = np.sort(sc)[::-1][:k]
+        row[:len(best)] = best
+        top.append(row)
+    return np.stack(top), cands
+
+
+def _assert_exact(scores, ids, oracle, tol=2e-5):
+    """Served (scores, ids) are the oracle's top-k: the same scores, and
+    every id a distinct candidate whose exact score is the one served."""
+    top, cands = oracle
+    scores, ids = np.asarray(scores), np.asarray(ids)
+    np.testing.assert_allclose(scores, top, rtol=tol, atol=tol)
+    for row_s, row_i, cand in zip(scores, ids, cands):
+        live = np.isfinite(row_s)
+        assert np.all(row_i[~live] == -1)
+        assert len(set(row_i[live].tolist())) == int(live.sum())
+        for sv, iv in zip(row_s[live], row_i[live]):
+            assert iv in cand and abs(cand[iv] - sv) <= tol * (1 + abs(sv))
+
+
+class TestProbedScanExact:
+    """The partition-major probe path equals the einsum path, and the exact
+    top-k over the quantized rows each query probes (the brute-force
+    oracle at full probe), across the grouping's edge cases."""
+
+    @staticmethod
+    def _index(rng, n, d, k_parts, capacity=None, seed=0):
+        v = _corpus(rng, n, d)
+        idx, _ = ivf_mod.build(jax.random.PRNGKey(seed), jnp.asarray(v),
+                               jnp.arange(n), n_partitions=k_parts, bits=8,
+                               capacity=capacity)
+        return v, idx
+
+    @pytest.mark.parametrize("qn", [1, 7, 64, 200])
+    def test_query_counts(self, rng, qn):
+        v, idx = self._index(rng, 1500, 32, 12)
+        q = jnp.asarray(v[rng.integers(0, 1500, qn)]
+                        + 0.05 * rng.normal(size=(qn, 32)).astype(np.float32))
+        probes, _ = ivf_mod.partitioner.assign_topk(q, idx.centroids, 3)
+        sk, ik = ivf_mod.search(idx, q, n_probe=3, k=10, impl="kernel")
+        se, ie = ivf_mod.search(idx, q, n_probe=3, k=10, impl="einsum")
+        np.testing.assert_allclose(np.asarray(sk), np.asarray(se),
+                                   rtol=2e-5, atol=2e-5)
+        _assert_exact(sk, ik, _quantized_oracle(idx, q, probes, 10))
+        _assert_exact(se, ie, _quantized_oracle(idx, q, probes, 10))
+
+    @pytest.mark.parametrize("qn", [7, 64])
+    def test_full_probe_is_brute_force(self, rng, qn):
+        v, idx = self._index(rng, 800, 32, 8)
+        q = jnp.asarray(_corpus(rng, qn, 32))
+        probes = jnp.tile(jnp.arange(8, dtype=jnp.int32), (qn, 1))
+        sk, ik = ivf_mod.search(idx, q, n_probe=8, k=10, impl="kernel")
+        _assert_exact(sk, ik, _quantized_oracle(idx, q, probes, 10))
+
+    @pytest.mark.parametrize("case", ["skewed", "unprobed"])
+    def test_probe_lists(self, rng, case):
+        """skewed: every query probes one partition, whose pairs span
+        several groups; unprobed: most partitions are probed by no query."""
+        v, idx = self._index(rng, 1500, 32, 12)
+        qn = 40 if case == "skewed" else 3
+        q = jnp.asarray(_corpus(rng, qn, 32))
+        if case == "skewed":
+            probes = jnp.full((qn, 1), 5, jnp.int32)
+        else:
+            probes = jnp.asarray([[1, 3], [3, 5], [5, 1]], jnp.int32)
+        n_probe = probes.shape[1]
+        sk, ik = ivf_mod.search(idx, q, n_probe=n_probe, k=10, impl="kernel",
+                                probes=probes)
+        se, ie = ivf_mod.search(idx, q, n_probe=n_probe, k=10, impl="einsum",
+                                probes=probes)
+        np.testing.assert_allclose(np.asarray(sk), np.asarray(se),
+                                   rtol=2e-5, atol=2e-5)
+        _assert_exact(sk, ik, _quantized_oracle(idx, q, probes, 10))
+
+    @pytest.mark.parametrize("block_n", [None, 32])
+    def test_partial_last_block_with_live_last_slot(self, rng, block_n):
+        """cap 50 is a multiple of no block; every partition is full, so
+        its last slot is live, and must be found."""
+        v, idx = self._index(rng, 600, 32, 4, capacity=50)
+        assert bool(jnp.all(idx.ids[:, -1] >= 0))
+        q = jnp.asarray(np.asarray(idx.data[:, -1], np.float32) + 128.0)
+        probes = jnp.asarray([[0, 1], [1, 2], [2, 3], [3, 0]], jnp.int32)
+        sv, part, slot = ivf_ops.scan_topk_probed(
+            q, idx.data, idx.vmin, idx.scale, idx.ids >= 0, probes, k=5,
+            block_n=block_n)
+        ids = jnp.where(slot >= 0,
+                        idx.ids[part, jnp.maximum(slot, 0)], -1)
+        _assert_exact(sv, ids, _quantized_oracle(idx, q, probes, 5))
+        assert set(np.asarray(slot).ravel().tolist()) >= {49}
+
+    @pytest.mark.parametrize("case", ["node_pass", "mvcc_dead"])
+    def test_validity_folds_into_the_scan(self, rng, case):
+        """node_pass pushdown and MVCC-dead rows are left out before the
+        top-k, never after it."""
+        n, d = 1500, 32
+        v, idx = self._index(rng, n, d, 12)
+        q = jnp.asarray(v[:16] + 0.02 * rng.normal(size=(16, d)).astype(
+            np.float32))
+        probes, _ = ivf_mod.partitioner.assign_topk(q, idx.centroids, 4)
+        if case == "node_pass":
+            visible = jnp.asarray(rng.random(n) < 0.3)
+            sk, ik = ivf_mod.search(idx, q, n_probe=4, k=10, impl="kernel",
+                                    node_pass=visible)
+            se, ie = ivf_mod.search(idx, q, n_probe=4, k=10, impl="einsum",
+                                    node_pass=visible)
+        else:
+            store = delta_mod.init(64, d, max_ids=n)
+            dead = jnp.asarray(rng.choice(n, 300, replace=False))
+            store = delta_mod.delete(store, dead)
+            visible = ~store.tombstones
+            run = functools.partial(delta_mod.search_with_delta, idx, store,
+                                    q, n_probe=4, k=10, probes=probes)
+            sk, ik = run(impl="kernel")
+            se, ie = run(impl="einsum")
+            assert not np.any(np.isin(np.asarray(ik), np.asarray(dead)))
+        np.testing.assert_allclose(np.asarray(sk), np.asarray(se),
+                                   rtol=2e-5, atol=2e-5)
+        _assert_exact(sk, ik, _quantized_oracle(idx, q, probes, 10, visible))
+
+    @pytest.mark.parametrize("case", ["k_over_fill", "k_chunks_over_rows"])
+    def test_k_beyond_the_rows(self, rng, case):
+        """k larger than a partition's fill, and k·chunk larger than the
+        rows probed: every live row comes back once, the rest pads."""
+        n, k = (50, 30) if case == "k_over_fill" else (200, 40)
+        v, idx = self._index(rng, n, 16, 8, seed=1)
+        q = jnp.asarray(v[:4])
+        probes, _ = ivf_mod.partitioner.assign_topk(q, idx.centroids, 2)
+        rows = int(jnp.max(jnp.sum(idx.ids[probes] >= 0, axis=(1, 2))))
+        assert rows < k * 16 and int(jnp.min(idx.counts)) < k
+        sk, ik = ivf_mod.search(idx, q, n_probe=2, k=k, impl="kernel")
+        se, ie = ivf_mod.search(idx, q, n_probe=2, k=k, impl="einsum")
+        np.testing.assert_allclose(np.asarray(sk), np.asarray(se),
+                                   rtol=2e-5, atol=2e-5)
+        _assert_exact(sk, ik, _quantized_oracle(idx, q, probes, k))
 
 
 class TestSparseFusion:

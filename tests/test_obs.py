@@ -438,6 +438,36 @@ def test_syncs_per_call_follow_the_plan(filtered_index, where, mode, syncs,
     assert obs.histogram("query.to_host").count == syncs
 
 
+@pytest.mark.parametrize("where,syncs", [
+    (None, 2), (("year", ">=", 2026), 3), (("year", ">=", 2001), 4)])
+def test_queries_per_partition_comes_from_the_tracked_probes(
+        filtered_index, monkeypatch, where, syncs):
+    """One seed scan records (query, probe) pairs over distinct probed
+    partitions, read from the probes the workload tracker copies: no
+    device read of its own. With the tracker off it records nothing."""
+    from repro.query.executor import search_bucketed
+    idx, vecs = filtered_index
+    m = idx.modalities["text"]
+    seen = []
+    record = m.workload.record
+    monkeypatch.setattr(m.workload, "record",
+                        lambda p: (seen.append(np.array(p)), record(p)))
+    search_bucketed(idx, vecs[:3], "text", k=5, where=where)
+    obs.reset()
+    seen.clear()
+    search_bucketed(idx, vecs[:3], "text", k=5, where=where)
+    (probes,) = seen
+    h = obs.histogram("seed_scan.queries_per_partition")
+    assert h.count == 1
+    assert h.total == probes.size / len(np.unique(probes))
+    assert obs.counter("executor.syncs").value == syncs
+    monkeypatch.setattr(m, "workload", None)
+    obs.reset()
+    search_bucketed(idx, vecs[:3], "text", k=5, where=where)
+    assert obs.histogram("seed_scan.queries_per_partition").count == 0
+    assert obs.counter("executor.syncs").value == syncs - 1
+
+
 def test_sync_guard_rehearsal_counts_each_plan_class(capsys):
     """``tools/sync_guard.py`` off the TPU: every class served, with the
     planner's mode and the syncs its plan implies (the guard itself only

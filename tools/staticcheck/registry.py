@@ -51,8 +51,7 @@ STATIC_INT_PARAMS: Dict[str, Dict[str, Optional[int]]] = {
     "search_raw": {"n_probe": 4, "k": 5},
     "_scan_delta": {"k": None, "margin": None},
     "scan_topk_quantized": {"k": None, "chunk": None, "block_n": None},
-    "scan_topk_quantized_batched": {"k": None, "chunk": None,
-                                    "block_n": None},
+    "scan_topk_probed": {"k": None, "chunk": None, "block_n": None},
     "brute_force": {"k": None},
     "multi_hop_batch": {"n_hops": None, "top_m": None},
     "frontier_expand": {"n_hops": None, "top_m": None},
@@ -278,20 +277,22 @@ def trace_entries() -> List[TraceEntry]:
                                k=_TOPK)
         return fn, (idx, d, q), {}
 
-    def kernel_batched():
+    def kernel_probed():
         import jax.numpy as jnp
         import numpy as np
-        from repro.kernels.ivf_topk.ops import scan_topk_quantized_batched
+        from repro.kernels.ivf_topk.ops import scan_topk_probed
         rng = np.random.default_rng(2)
-        m = 1024
-        fn = functools.partial(scan_topk_quantized_batched, k=_TOPK,
-                               chunk=16, block_n=512)
+        k_parts, cap = 8, 256
+        fn = functools.partial(scan_topk_probed, k=_TOPK, chunk=16,
+                               block_n=128)
         args = (jnp.asarray(rng.normal(size=(_Q, _D)).astype(np.float32)),
-                jnp.asarray(rng.integers(-128, 127, size=(_Q, m, _D)
+                jnp.asarray(rng.integers(-128, 127, size=(k_parts, cap, _D)
                                          ).astype(np.int8)),
-                jnp.zeros((_Q, m), jnp.float32),
-                jnp.ones((_Q, m), jnp.float32),
-                jnp.ones((_Q, m), bool))
+                jnp.zeros((k_parts, cap), jnp.float32),
+                jnp.ones((k_parts, cap), jnp.float32),
+                jnp.ones((k_parts, cap), bool),
+                jnp.asarray(rng.integers(0, k_parts, size=(_Q, 4)),
+                            jnp.int32))
         return fn, args, {}
 
     def traverse():
@@ -310,7 +311,7 @@ def trace_entries() -> List[TraceEntry]:
 
     # HMG101 threshold: 2x the provable rescore gather (Q · k·chunk · d).
     # The smallest slab-scale dequant at canonical shapes is ≥ Q·M·d with
-    # M = n_probe·cap ≈ 4·129, comfortably above it.
+    # M = n_probe·cap, comfortably above it.
     rescore_budget = 2 * _Q * _TOPK * 16 * _D
     return [
         TraceEntry("ivf.search[int8-kernel]", ivf_search_kernel,
@@ -319,7 +320,7 @@ def trace_entries() -> List[TraceEntry]:
                    max_upcast_elems=rescore_budget),
         TraceEntry("delta.search_with_delta[int8-kernel]", delta_search,
                    max_upcast_elems=rescore_budget),
-        TraceEntry("ivf_topk.scan_topk_quantized_batched", kernel_batched,
+        TraceEntry("ivf_topk.scan_topk_probed", kernel_probed,
                    max_upcast_elems=rescore_budget),
         TraceEntry("traversal.multi_hop_batch", traverse,
                    max_upcast_elems=None),
@@ -339,8 +340,8 @@ BUDGET_ENTRIES: Sequence[Tuple[str, str, str]] = (
     ("delta._scan_delta", "repro.core.delta", "_scan_delta"),
     ("kernels.scan_topk_quantized",
      "repro.kernels.ivf_topk.ops", "scan_topk_quantized"),
-    ("kernels.scan_topk_quantized_batched",
-     "repro.kernels.ivf_topk.ops", "scan_topk_quantized_batched"),
+    ("kernels.scan_topk_probed",
+     "repro.kernels.ivf_topk.ops", "scan_topk_probed"),
     ("index._fuse_candidates", "repro.core.index", "_fuse_candidates"),
     ("executor._fuse_dense", "repro.query.executor", "_fuse_dense"),
     ("executor._rescore", "repro.query.executor", "_rescore"),
